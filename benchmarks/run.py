@@ -22,6 +22,7 @@ import traceback
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks import common
+from repro.launch.compile_cache import enable_compile_cache
 from benchmarks import (bench_memory_fraction, bench_kernel_speedup,
                         bench_e2e, bench_energy, bench_batch_scaling,
                         bench_comm_bytes, bench_hetero_overlap,
@@ -60,6 +61,7 @@ def main() -> None:
                          "alias is refreshed alongside it (a scratch --out "
                          "leaves the committed alias untouched)")
     args = ap.parse_args()
+    enable_compile_cache()
     common.set_smoke(args.smoke)
     print("name,us_per_call,derived")
     failures = 0

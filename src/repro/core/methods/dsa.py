@@ -52,7 +52,7 @@ def dsa_init(key, cfg: ArchConfig, mem: MemoryConfig, stacked: bool = True):
 
     n = cfg.n_layers if stacked else 1
     keys = jax.random.split(key, n)
-    p = jax.vmap(one)(keys)
+    p = jax.lax.map(one, keys)       # one layer's float32 draw at a time
     return p if stacked else jax.tree.map(lambda a: a[0], p)
 
 

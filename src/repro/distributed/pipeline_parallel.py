@@ -17,7 +17,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def gpipe_forward(
@@ -64,11 +63,11 @@ def gpipe_forward(
         # (ppermute is a permutation — multicast needs all_gather + select)
         return jax.lax.all_gather(buf, axis)[n_stages - 1]
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -19,7 +19,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.kernels import ops
 
@@ -82,12 +81,12 @@ def distributed_relevancy_topk(
         return top_v, top_i
 
     seq_spec = axes if len(axes) > 1 else axes[0]
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(ba), P(ba, seq_spec, None), P(ba)),
         out_specs=(P(ba), P(ba)),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, keys, weights)
 
@@ -115,11 +114,11 @@ def sharded_page_add(kidx: jnp.ndarray, delta: jnp.ndarray, pg,
         return jax.lax.dynamic_update_slice(kx, new.astype(kx.dtype),
                                             (0, idx, 0))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(ba, seq_spec, None), P(ba), P()),
         out_specs=P(ba, seq_spec, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(kidx, delta, jnp.asarray(pg, jnp.int32)[None])
 
@@ -205,12 +204,12 @@ def distributed_paged_sparse_decode(
         return ops.lse_merge(outs, lses)
 
     seq_spec = axes if len(axes) > 1 else axes[0]
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(ba), P(ba, seq_spec, None, None),
                   P(ba, seq_spec, None, None), P(ba), P(ba)),
         out_specs=(P(ba), P(ba)),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k_cache, v_cache, page_ids, lengths)

@@ -14,7 +14,6 @@ retrieval path every time the corpus grows.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,16 +27,19 @@ def _kernel(nd_ref, tf_ref, dl_ref, idf_ref, vals_ref, idx_ref,
             *, k1: float, b: float, avgdl: float, bd: int, c: int):
     j = pl.program_id(1)
     tf = tf_ref[0].astype(jnp.float32)        # [bd, T]
-    dl = dl_ref[0].astype(jnp.float32)        # [bd]
-    idf = idf_ref[0].astype(jnp.float32)      # [T]
-    denom = tf + k1 * (1.0 - b + b * dl[:, None] / avgdl)
-    scores = (tf * (k1 + 1.0) / denom) @ idf  # [bd]
-    idx = j * bd + jax.lax.iota(jnp.int32, bd)
-    scores = jnp.where(idx < nd_ref[0], scores, -jnp.inf)
-    top_v, top_pos = bitonic_topk(scores[None, :],
-                                  jax.lax.iota(jnp.int32, bd)[None, :], c)
-    vals_ref[0, 0] = top_v[0]
-    idx_ref[0, 0] = j * bd + top_pos[0]
+    dl = dl_ref[0].astype(jnp.float32)        # [bd, 1]
+    idf = idf_ref[0].astype(jnp.float32)      # [1, T]
+    denom = tf + k1 * (1.0 - b + b * dl / avgdl)
+    sat = tf * (k1 + 1.0) / denom             # [bd, T]
+    scores = jax.lax.dot_general(           # float32 passes, as relevancy
+        idf, sat, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)   # [1, bd] lane-dense row
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, bd), 1)
+    scores = jnp.where(j * bd + pos < nd_ref[0], scores, -jnp.inf)
+    top_v, top_pos = bitonic_topk(scores, pos, c)
+    vals_ref[0, 0] = top_v
+    idx_ref[0, 0] = j * bd + top_pos
 
 
 @functools.partial(
@@ -55,16 +57,17 @@ def bm25_topk_candidates(
     b: float = 0.75,
     avgdl: float = 100.0,
     valid=0,               # live doc count (traced ok); 0 -> D
-    interpret: Optional[bool] = None,  # None -> backend-aware (CPU only)
+    interpret: bool,
 ):
-    """Per-block BM25 top-c candidates: (vals [B,nb,c], idx [B,nb,c])."""
+    """Per-block BM25 top-c candidates: (vals [B,nb,c], idx [B,nb,c]).
+
+    doc_len rides as a [B, D, 1] column and idf as a [B, 1, T] row so every
+    block's last two dims are full or tile-aligned (legal for Mosaic)."""
     B, D, T = tf.shape
     block = min(block, D)
     assert D % block == 0
     nb = D // block
     c = min(c, block)
-    if interpret is None:  # match ops._interp(): compile via Mosaic off-CPU
-        interpret = jax.default_backend() == "cpu"
     nd = jnp.asarray(valid, jnp.int32)
     nd = jnp.where(nd > 0, nd, D).reshape(1)
     kern = functools.partial(_kernel, k1=k1, b=b, avgdl=avgdl, bd=block, c=c)
@@ -73,20 +76,22 @@ def bm25_topk_candidates(
         grid=(B, nb),
         in_specs=[
             pl.BlockSpec((1, block, T), lambda bi, j, nd: (bi, j, 0)),
-            pl.BlockSpec((1, block), lambda bi, j, nd: (bi, j)),
-            pl.BlockSpec((1, T), lambda bi, j, nd: (bi, 0)),
+            pl.BlockSpec((1, block, 1), lambda bi, j, nd: (bi, j, 0)),
+            pl.BlockSpec((1, 1, T), lambda bi, j, nd: (bi, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, c), lambda bi, j, nd: (bi, j, 0)),
-            pl.BlockSpec((1, 1, c), lambda bi, j, nd: (bi, j, 0)),
+            pl.BlockSpec((1, 1, 1, c), lambda bi, j, nd: (bi, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, c), lambda bi, j, nd: (bi, j, 0, 0)),
         ],
     )
-    return pl.pallas_call(
+    vals, idx = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, nb, c), jnp.float32),
-            jax.ShapeDtypeStruct((B, nb, c), jnp.int32),
+            jax.ShapeDtypeStruct((B, nb, 1, c), jnp.float32),
+            jax.ShapeDtypeStruct((B, nb, 1, c), jnp.int32),
         ],
         interpret=interpret,
-    )(nd, tf, doc_len, idf)
+        name="bm25_topk",
+    )(nd, tf, doc_len.reshape(B, D, 1), idf.reshape(B, 1, T))
+    return vals.reshape(B, nb, c), idx.reshape(B, nb, c)

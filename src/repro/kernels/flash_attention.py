@@ -75,7 +75,7 @@ def flash_attention(
     bq: int = 512,
     bk: int = 512,
     window: int = 0,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     B, S, H, dh = q.shape
     KV = k.shape[2]
@@ -108,5 +108,6 @@ def flash_attention(
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     return jnp.moveaxis(out[:, :, :S], 2, 1)  # [B, S, H, dh]

@@ -1,8 +1,9 @@
 """Public jit'd kernel API. On CPU the Pallas kernels run in interpret mode
 (exact same kernel body, validated against ref.py); on TPU they compile via
-Mosaic. ``use_pallas(False)`` routes everything through the ref oracles
-(useful under 512-device dry-run lowering where interpret-mode overhead in
-the traced graph is unwanted).
+Mosaic. ``_interp`` is the one place that decides: the kernel modules take
+``interpret`` as a required argument. ``use_pallas(False)`` routes
+everything through the ref oracles (useful under 512-device dry-run
+lowering where interpret-mode overhead in the traced graph is unwanted).
 """
 from __future__ import annotations
 
@@ -38,11 +39,13 @@ def _interp() -> bool:
 
 
 def _pow2_block(n: int, want: int) -> int:
-    """Largest power-of-two block <= want that is also >= 2."""
+    """Largest power-of-two block <= want, and at least 8: Mosaic refuses a
+    partial block below the 8-row sublane tile, so a shorter axis is padded
+    up to one whole block instead."""
     b = 1
     while b * 2 <= min(n, want):
         b *= 2
-    return max(b, 2)
+    return max(b, 8)
 
 
 def relevancy_topk(q, keys, weights, k: int, *, block: int = 2048,

@@ -97,7 +97,7 @@ def _kernel(k_ref, min_ref, max_ref):
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def page_minmax(k_cache: jnp.ndarray, *, page_size: int = 64,
-                interpret: bool = True):
+                interpret: bool):
     """[B, S, KV, dh] -> (min, max) [B, S/ps, KV, dh] fp32."""
     B, S, KV, dh = k_cache.shape
     ps = page_size
@@ -117,4 +117,5 @@ def page_minmax(k_cache: jnp.ndarray, *, page_size: int = 64,
             jax.ShapeDtypeStruct((B, n_pages, KV, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="page_minmax",
     )(kp)

@@ -23,7 +23,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels.bitonic import bitonic_topk
@@ -34,18 +33,23 @@ def _kernel(q_ref, k_ref, w_ref, vals_ref, idx_ref, *, block: int, c: int,
     j = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32)          # [Hq, dk]
     keys = k_ref[0].astype(jnp.float32)       # [block, dk]
-    w = w_ref[0].astype(jnp.float32)          # [Hq]
-    # 1) multi-head inner product on the MXU
-    dots = jnp.dot(keys, q.T, preferred_element_type=jnp.float32)  # [block, Hq]
-    # 2) weighted ReLU reduction -> one score per key
-    scores = jax.nn.relu(dots) @ w            # [block]
-    idx = j * block + jax.lax.iota(jnp.int32, block)
-    scores = jnp.where(idx < valid_len, scores, -jnp.inf)
+    w = w_ref[0].astype(jnp.float32)          # [1, Hq]
+    # 1) multi-head inner product on the MXU, keys on the lane axis;
+    #    float32 passes (the default rounds the operands to bf16, which
+    #    reorders near-tied scores against the float32 oracle)
+    exact = jax.lax.Precision.HIGHEST
+    dots = jax.lax.dot_general(
+        q, keys, (((1,), (1,)), ((), ())), precision=exact,
+        preferred_element_type=jnp.float32)   # [Hq, block]
+    # 2) weighted ReLU reduction -> one lane-dense score row
+    scores = jnp.dot(w, jax.nn.relu(dots), precision=exact,
+                     preferred_element_type=jnp.float32)  # [1, block]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    scores = jnp.where(j * block + pos < valid_len, scores, -jnp.inf)
     # 3) in-VMEM bitonic top-c (no HBM writeback of raw scores)
-    top_v, top_pos = bitonic_topk(scores[None, :],
-                                  jax.lax.iota(jnp.int32, block)[None, :], c)
-    vals_ref[0, 0] = top_v[0]
-    idx_ref[0, 0] = j * block + top_pos[0]
+    top_v, top_pos = bitonic_topk(scores, pos, c)
+    vals_ref[0, 0] = top_v
+    idx_ref[0, 0] = j * block + top_pos
 
 
 @functools.partial(
@@ -60,9 +64,13 @@ def relevancy_topk_candidates(
     block: int = 2048,
     c: int = 0,            # candidates per block; 0 -> min(block, S)
     valid_len: int = 0,    # 0 -> S (static; dynamic masking happens on merge)
-    interpret: bool = True,
+    interpret: bool,
 ):
-    """Per-block candidates: (vals [B, nb, c], idx [B, nb, c])."""
+    """Per-block candidates: (vals [B, nb, c], idx [B, nb, c]).
+
+    Every block's last two dims are either full array dims or (block, dk),
+    so the call is legal for Mosaic: weights ride as [B, 1, Hq] and the
+    candidates leave as [B, nb, 1, c] rows."""
     B, S, dk = keys.shape
     Hq = q.shape[1]
     block = min(block, S)
@@ -72,24 +80,26 @@ def relevancy_topk_candidates(
     c = min(c, block)
     valid_len = valid_len or S
     kern = functools.partial(_kernel, block=block, c=c, valid_len=valid_len)
-    return pl.pallas_call(
+    vals, idx = pl.pallas_call(
         kern,
         grid=(B, nb),
         in_specs=[
             pl.BlockSpec((1, Hq, dk), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, block, dk), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, Hq), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, 1, Hq), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, c), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, c), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, 1, 1, c), lambda b, j: (b, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, c), lambda b, j: (b, j, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, nb, c), jnp.float32),
-            jax.ShapeDtypeStruct((B, nb, c), jnp.int32),
+            jax.ShapeDtypeStruct((B, nb, 1, c), jnp.float32),
+            jax.ShapeDtypeStruct((B, nb, 1, c), jnp.int32),
         ],
         interpret=interpret,
-    )(q, keys, weights)
+        name="relevancy_topk",
+    )(q, keys, weights.reshape(B, 1, Hq))
+    return vals.reshape(B, nb, c), idx.reshape(B, nb, c)
 
 
 def merge_candidates(vals: jnp.ndarray, idx: jnp.ndarray, k: int):

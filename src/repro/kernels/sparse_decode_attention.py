@@ -22,9 +22,9 @@ NEG_INF = -1e30
 
 
 def _kernel(pages_ref, length_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
-            m_scr, l_scr, acc_scr, *, ps: int, n_sel: int):
+            m_scr, l_scr, acc_scr, *, ps: int, n_sel: int, kv: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -34,30 +34,32 @@ def _kernel(pages_ref, length_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
 
     page_id = pages_ref[b, j]
     length = length_ref[b]
-    q = q_ref[0, 0].astype(jnp.float32)            # [G, dh]
-    k = k_ref[0, 0, :, 0].astype(jnp.float32)      # [ps, dh]
-    v = v_ref[0, 0, :, 0].astype(jnp.float32)      # [ps, dh]
-    dh = q.shape[-1]
-    sc = jnp.dot(q / np.sqrt(dh), k.T,
-                 preferred_element_type=jnp.float32)  # [G, ps]
-    tok = page_id * ps + jax.lax.iota(jnp.int32, ps)
-    valid = (page_id >= 0) & (tok < length)
-    sc = jnp.where(valid[None, :], sc, NEG_INF)
+    tok = page_id * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+    valid = (page_id >= 0) & (tok < length)            # [1, ps]
+    # one DMA brings the page of every KV head; heads run in a static loop
+    for h in range(kv):
+        q = q_ref[0, h].astype(jnp.float32)            # [G, dh]
+        k = k_ref[0, 0, h].astype(jnp.float32)         # [ps, dh]
+        v = v_ref[0, 0, h].astype(jnp.float32)         # [ps, dh]
+        dh = q.shape[-1]
+        sc = jnp.dot(q / np.sqrt(dh), k.T,
+                     preferred_element_type=jnp.float32)  # [G, ps]
+        sc = jnp.where(valid, sc, NEG_INF)
 
-    m_prev = m_scr[...]                            # [G, 1]
-    m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
-    p = jnp.exp(sc - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+        m_prev = m_scr[h]                              # [G, 1]
+        m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[h] = l_scr[h] * corr + p.sum(axis=-1, keepdims=True)
+        acc_scr[h] = acc_scr[h] * corr + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_scr[h] = m_new
 
     @pl.when(j == n_sel - 1)
     def _finish():
-        l = l_scr[...]
-        out_ref[0, 0] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(out_ref.dtype)
-        lse_ref[0, 0] = (m_scr[...] + jnp.log(jnp.maximum(l, 1e-30)))[:, 0]
+        l = l_scr[...]                                 # [KV, G, 1]
+        out_ref[0] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(out_ref.dtype)
+        lse_ref[0] = m_scr[...] + jnp.log(jnp.maximum(l, 1e-30))
 
 
 @functools.partial(
@@ -71,9 +73,15 @@ def paged_decode_attention(
     length: jnp.ndarray,    # [B] int32 valid token count
     *,
     page_size: int = 64,
-    interpret: bool = True,
+    interpret: bool,
 ):
-    """-> (out [B, Hq, dh] fp32, lse [B, Hq] fp32)."""
+    """-> (out [B, Hq, dh] fp32, lse [B, Hq] fp32).
+
+    Pages are laid out head-major ([B, n_pages, KV, ps, dh]) so one block
+    is a whole page of every KV head, whose last two dims (ps, dh) are full
+    array dims — legal for Mosaic at any page size. That layout costs an
+    XLA transpose of the whole cache view per call, O(context) bytes, until
+    the pool itself is stored head-major."""
     B, S, KV, dh = k_cache.shape
     Hq = q.shape[1]
     G = Hq // KV
@@ -82,31 +90,30 @@ def paged_decode_attention(
     n_pages = S // ps
     n_sel = page_ids.shape[1]
     qg = q.reshape(B, KV, G, dh)
-    kp = k_cache.reshape(B, n_pages, ps, KV, dh)
-    vp = v_cache.reshape(B, n_pages, ps, KV, dh)
+    kp = k_cache.reshape(B, n_pages, ps, KV, dh).transpose(0, 1, 3, 2, 4)
+    vp = v_cache.reshape(B, n_pages, ps, KV, dh).transpose(0, 1, 3, 2, 4)
     length = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
 
-    kern = functools.partial(_kernel, ps=ps, n_sel=n_sel)
+    def page_block(b, j, pages, lens):
+        return (b, jnp.maximum(pages[b, j], 0), 0, 0, 0)
+
+    kern = functools.partial(_kernel, ps=ps, n_sel=n_sel, kv=KV)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KV, n_sel),
+        grid=(B, n_sel),
         in_specs=[
-            pl.BlockSpec((1, 1, G, dh), lambda b, h, j, pages, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, ps, 1, dh),
-                         lambda b, h, j, pages, lens: (
-                             b, jnp.maximum(pages[b, j], 0), 0, h, 0)),
-            pl.BlockSpec((1, 1, ps, 1, dh),
-                         lambda b, h, j, pages, lens: (
-                             b, jnp.maximum(pages[b, j], 0), 0, h, 0)),
+            pl.BlockSpec((1, KV, G, dh), lambda b, j, pages, lens: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, KV, ps, dh), page_block),
+            pl.BlockSpec((1, 1, KV, ps, dh), page_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, G, dh), lambda b, h, j, pages, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, h, j, pages, lens: (b, h, 0)),
+            pl.BlockSpec((1, KV, G, dh), lambda b, j, pages, lens: (b, 0, 0, 0)),
+            pl.BlockSpec((1, KV, G, 1), lambda b, j, pages, lens: (b, 0, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, dh), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, dh), jnp.float32),
         ],
     )
     out, lse = pl.pallas_call(
@@ -114,9 +121,10 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, KV, G, dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, KV, G, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="paged_decode_attention",
     )(page_ids, length, qg, kp, vp)
     return out.reshape(B, Hq, dh), lse.reshape(B, Hq)
 

@@ -166,8 +166,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         fsdp = (False if (variant.startswith("optimized")
                           and shape.kind == "decode") else None)
         specs = input_specs(cfg, shape, mesh, tp=tp, fsdp=fsdp)
-        from repro.launch.mesh import use_mesh
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             if shape.kind == "train":
                 step, accum = build_train_step(cfg, shape, mesh, tp, variant)
                 rec["accum"] = accum

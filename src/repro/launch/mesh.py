@@ -1,33 +1,18 @@
-"""Production mesh construction (version-portable).
+"""Production mesh construction.
 
 A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state — the dry-run must set XLA_FLAGS before any jax
-initialization.
-
-``jax.sharding.AxisType`` / explicit ``axis_types`` only exist in newer JAX;
-on older releases every mesh axis is implicitly Auto, so the guarded kwargs
-degrade to a plain ``jax.make_mesh``/``Mesh`` call. ``use_mesh`` papers over
-the ``jax.set_mesh`` (new) vs ``with mesh:`` (old) context difference the
-same way. Tests that spawn multi-device subprocesses import these helpers
-instead of touching ``AxisType`` directly.
+initialization. Every axis is explicitly ``AxisType.Auto``; activate a mesh
+for jitted computations with ``jax.set_mesh``.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-try:  # JAX >= 0.5-era explicit axis types
-    from jax.sharding import AxisType
 
-    def _auto_kwargs(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:  # older JAX: all axes are Auto already
-    AxisType = None
-
-    def _auto_kwargs(n_axes: int) -> dict:
-        return {}
+def _auto_kwargs(n_axes: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -49,14 +34,6 @@ def mesh_from_devices(devices, axes=("seq",)) -> Mesh:
     import numpy as np
 
     return Mesh(np.array(devices), tuple(axes), **_auto_kwargs(len(axes)))
-
-
-def use_mesh(mesh: Mesh):
-    """Context manager that activates ``mesh`` for jitted computations:
-    ``jax.set_mesh`` where it exists, the classic ``with mesh:`` otherwise."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return contextlib.nullcontext() if mesh is None else mesh
 
 
 def split_mesh_roles(mesh: Mesh, prefill_fraction: float = 0.5):

@@ -28,6 +28,14 @@ overlapped against decode. Composes with ``--offload``.
 ``--replicas N`` serves the same request stream through a :class:`Router`
 over N engine replicas, each pinned to its own device group, sharing one
 retrieval corpus — the fleet-scale front of the same request-level API.
+
+``--width smoke`` (the default) builds the CPU-sized ``.smoke()`` config;
+``--width full`` keeps the published widths of ``--arch`` and cuts only the
+depth (``--layers``), with page sizes from the ``ServeConfig`` defaults.
+Weights are random, drawn from ``--seed``:
+
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-32b \
+        --width full --layers 8 --method dsa --slots 2 --prompt-len 4608
 """
 from __future__ import annotations
 
@@ -39,14 +47,42 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import Engine, OffloadConfig, Request, Router, \
     ServeConfig
 
 
+def build_model(arch: str, *, width: str = "smoke", layers: int = 0,
+                tp: int = 4, seed: int = 0):
+    """(cfg, params): the smoke config, or the published widths with the
+    depth cut to ``layers`` (0 keeps the config's own depth)."""
+    cfg = get_arch(arch)
+    if width == "smoke":
+        cfg = cfg.smoke()
+    if layers:
+        cfg = cfg.replace(n_layers=layers)
+    return cfg, init_params(cfg, jax.random.PRNGKey(seed), tp=tp)
+
+
+def serve_config(*, width: str = "smoke", **kw) -> ServeConfig:
+    """ServeConfig for the launcher; the smoke width keeps its 8-token DSA
+    page, the full width takes every page size from the defaults."""
+    if width == "smoke":
+        kw.setdefault("page", 8)
+    return ServeConfig(**kw)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
+    ap.add_argument("--width", default="smoke", choices=["smoke", "full"],
+                    help="smoke = CPU-sized .smoke() config; full = the "
+                         "published widths of --arch")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to this many layers (0 = keep)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
     ap.add_argument("--method", default="dsa",
                     choices=["none", "dsa", "seer", "lserve"])
     ap.add_argument("--requests", type=int, default=8)
@@ -94,8 +130,9 @@ def main(argv=None):
     except ValueError as e:
         ap.error(str(e))
 
-    cfg = get_arch(args.arch).smoke()
-    params = init_params(cfg, jax.random.PRNGKey(0), tp=args.tp)
+    enable_compile_cache()
+    cfg, params = build_model(args.arch, width=args.width,
+                              layers=args.layers, tp=args.tp, seed=args.seed)
     if args.disaggregate and jax.device_count() >= 2:
         from repro.launch.mesh import make_mesh, split_mesh_roles
         mesh = make_mesh((jax.device_count() // 1, 1), ("data", "model"))
@@ -123,20 +160,21 @@ def main(argv=None):
         mode=offload, validate=args.offload_validate,
         shards=args.offload_shards if offload != "off" else 1,
         main_mesh=args.main_mesh if offload != "off" else 1)
-    sc = ServeConfig(max_len=args.prompt_len + args.max_new + extra,
-                     n_slots=args.slots, method=args.method,
-                     tp=args.tp, page=8, offload_cfg=offload_cfg,
-                     fused_steps=args.fused_steps, retrieval=retrieval)
-    rng = np.random.default_rng(0)
+    sc = serve_config(width=args.width,
+                      max_len=args.prompt_len + args.max_new + extra,
+                      n_slots=args.slots, method=args.method,
+                      tp=args.tp, offload_cfg=offload_cfg,
+                      fused_steps=args.fused_steps, retrieval=retrieval)
+    rng = np.random.default_rng(args.seed)
     reqs = [Request(i, rng.integers(0, cfg.vocab_size,
                                     size=args.prompt_len), args.max_new)
             for i in range(args.requests)]
     if args.replicas > 1:
         front = Router.build(cfg, params, sc, n_replicas=args.replicas,
-                             key=jax.random.PRNGKey(1))
+                             key=jax.random.PRNGKey(args.seed + 1))
         engines = [r.engine for r in front.replicas]
     else:
-        eng = Engine(cfg, params, sc, key=jax.random.PRNGKey(1))
+        eng = Engine(cfg, params, sc, key=jax.random.PRNGKey(args.seed + 1))
         front, engines = eng, [eng]
     t0 = time.perf_counter()
     handles = [front.submit(r) for r in reqs]

@@ -77,39 +77,55 @@ def _tf_layer_init(key, cfg: ArchConfig, tp: int) -> Params:
 
 
 def _stacked(init_fn, key, n: int):
-    return jax.vmap(init_fn)(jax.random.split(key, n))
+    """Layer-stacked params [n, ...], drawn one layer at a time (lax.map),
+    so no [n, ...] float32 draw is ever materialized."""
+    return jax.lax.map(init_fn, jax.random.split(key, n))
 
 
 def init_params(cfg: ArchConfig, key, tp: int = 16) -> Params:
+    """Random params from ``key``. Each part is drawn by its own jitted
+    call, so at published widths the float32 draw behind a bf16 weight is
+    never held for more than one matrix at a time."""
     ke, kl, kf, kh = jax.random.split(key, 4)
     params: Params = {
-        "embed": L.embed_init(ke, cfg),
+        "embed": _embed_init(ke, cfg),
         "final_norm": L.rms_norm_init(cfg.d_model, None),
-        "lm_head": L.lm_head_init(kh, cfg),
+        "lm_head": _lm_head_init(kh, cfg),
     }
+    params.update(_body_init(kl, cfg, tp))
+    return params
+
+
+_embed_init = jax.jit(L.embed_init, static_argnums=1)
+_lm_head_init = jax.jit(L.lm_head_init, static_argnums=1)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _body_init(key, cfg: ArchConfig, tp: int) -> Params:
     if cfg.family == "hybrid":
         n_super = cfg.n_layers // cfg.shared_attn_every  # 13
         per = cfg.shared_attn_every                      # 6
         tail = cfg.n_layers - n_super * per              # 3
-        kb, kt, ks = jax.random.split(kl, 3)
+        kb, kt, ks = jax.random.split(key, 3)
         body_keys = jax.random.split(kb, n_super * per).reshape(n_super, per, 2)
-        params["body"] = jax.vmap(jax.vmap(lambda k: _mamba_layer_init(k, cfg)))(
-            body_keys)
-        params["tail"] = _stacked(lambda k: _mamba_layer_init(k, cfg), kt, tail)
-        params["shared"] = _tf_layer_init(ks, cfg, tp)
-    elif cfg.xlstm_pattern:
+        mamba = lambda k: _mamba_layer_init(k, cfg)
+        return {"body": jax.lax.map(lambda row: jax.lax.map(mamba, row),
+                                    body_keys),
+                "tail": _stacked(mamba, kt, tail),
+                "shared": _tf_layer_init(ks, cfg, tp)}
+    if cfg.xlstm_pattern:
         nb = cfg.n_layers // len(cfg.xlstm_pattern)
-        km, ks = jax.random.split(kl)
-        params["mlstm"] = _stacked(
-            lambda k: {"pre": L.rms_norm_init(cfg.d_model, None),
-                       "blk": X.mlstm_init(k, cfg)}, km, nb)
-        params["slstm"] = _stacked(
-            lambda k: {"pre": L.rms_norm_init(cfg.d_model, None),
-                       "blk": X.slstm_init(k, cfg)}, ks, nb)
-    else:
-        params["layers"] = _stacked(
-            lambda k: _tf_layer_init(k, cfg, tp), kl, cfg.n_layers)
-    return params
+        km, ks = jax.random.split(key)
+        return {
+            "mlstm": _stacked(
+                lambda k: {"pre": L.rms_norm_init(cfg.d_model, None),
+                           "blk": X.mlstm_init(k, cfg)}, km, nb),
+            "slstm": _stacked(
+                lambda k: {"pre": L.rms_norm_init(cfg.d_model, None),
+                           "blk": X.slstm_init(k, cfg)}, ks, nb),
+        }
+    return {"layers": _stacked(lambda k: _tf_layer_init(k, cfg, tp), key,
+                               cfg.n_layers)}
 
 
 def _mamba_layer_init(key, cfg: ArchConfig) -> Params:

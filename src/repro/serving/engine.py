@@ -227,7 +227,9 @@ class Engine:
         sparse_fn = None
         if sc.method != "none" and cfg.family != "ssm":
             init_fn, mk = get_sparse_method(sc.method)
-            self.sparse_params = init_fn(
+            # jitted like init_params: no eager float32 draw of a stack
+            self.sparse_params = jax.jit(
+                init_fn, static_argnums=(1, 2), static_argnames="stacked")(
                 key if key is not None else jax.random.PRNGKey(0),
                 cfg, self.mem, stacked=cfg.family != "hybrid")
             kw = {"page": sc.page} if sc.method == "dsa" else {}

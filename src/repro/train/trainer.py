@@ -16,7 +16,6 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ArchConfig
 from repro.distributed import checkpoint as ckpt

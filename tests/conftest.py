@@ -1,7 +1,5 @@
 import os
-import random
 import sys
-import types
 
 # src/ layout import path (tests run with or without PYTHONPATH=src)
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -10,88 +8,3 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # device count it was launched under: 1 (default) and 2 (CI's fast split,
 # which exercises the hetero offload executor's real main/offload split).
 # Many-device tests spawn subprocesses that set the flag themselves.
-
-
-# ---------------------------------------------------------------------------
-# hypothesis fallback shim
-#
-# The property tests (test_bitonic / test_pipeline / test_train) use the real
-# hypothesis package when it is installed (CI installs the [test] extra from
-# pyproject.toml). Hermetic environments without it get this minimal,
-# deterministic example-drawing shim instead of failing collection outright.
-# It covers exactly the API surface those tests use: @given, @settings,
-# st.integers/floats/lists/booleans and @st.composite.
-# ---------------------------------------------------------------------------
-
-try:  # pragma: no cover - exercised only when hypothesis is absent
-    import hypothesis  # noqa: F401
-except ImportError:
-    class _Strategy:
-        def __init__(self, draw_fn):
-            self._draw = draw_fn
-
-        def draw(self, rng):
-            return self._draw(rng)
-
-    def _integers(lo, hi):
-        return _Strategy(lambda rng: rng.randint(lo, hi))
-
-    def _floats(lo, hi, width=64, allow_subnormal=True):
-        import numpy as _np
-
-        def draw(rng):
-            x = rng.uniform(lo, hi)
-            return float(_np.float32(x)) if width == 32 else x
-
-        return _Strategy(draw)
-
-    def _booleans():
-        return _Strategy(lambda rng: rng.random() < 0.5)
-
-    def _lists(elem, min_size=0, max_size=10):
-        return _Strategy(lambda rng: [
-            elem.draw(rng) for _ in range(rng.randint(min_size, max_size))])
-
-    def _composite(fn):
-        def build(*args, **kwargs):
-            def draw_example(rng):
-                return fn(lambda s: s.draw(rng), *args, **kwargs)
-            return _Strategy(draw_example)
-        return build
-
-    def _given(*strategies):
-        def deco(f):
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_shim_max_examples", 10)
-                rng = random.Random(f.__qualname__)  # deterministic per test
-                for _ in range(n):
-                    vals = [s.draw(rng) for s in strategies]
-                    f(*args, *vals, **kwargs)
-            # NOT functools.wraps: pytest must see the zero-arg signature,
-            # not the strategy parameters (it would treat them as fixtures)
-            wrapper.__name__ = f.__name__
-            wrapper.__doc__ = f.__doc__
-            wrapper._shim_given = True
-            return wrapper
-        return deco
-
-    def _settings(max_examples=10, deadline=None, **_kw):
-        def deco(f):
-            f._shim_max_examples = max_examples
-            return f
-        return deco
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.floats = _floats
-    _st.booleans = _booleans
-    _st.lists = _lists
-    _st.composite = _composite
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.__is_shim__ = True
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st

@@ -74,7 +74,7 @@ def test_sharded_train_step_matches_single_device():
     out = _run(r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import get_arch
 from repro.models import init_params
 from repro.train import make_train_step, init_opt_state, TrainConfig
@@ -95,7 +95,7 @@ opt_sh = init_opt_state(params_sh)
 opt_ref = init_opt_state(params)
 # run the sharded step FIRST: device_put may alias replicated leaves, and
 # the single-device step donates (deletes) its inputs.
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     p2, _, st2 = jax.jit(step)(params_sh, opt_sh, b)
 p_ref, _, st_ref = step(params, opt_ref, b)
 assert abs(float(st_ref["loss"]) - float(st2["loss"])) < 2e-3, (st_ref["loss"], st2["loss"])

@@ -11,8 +11,7 @@ Load-bearing properties:
     step on the up link (k (val, idx) pairs — never scores, never KV), and
     per-shard per-step traffic stays below one KV page;
   * the sharded top-k merge equals the exact reference top-k for random
-    shard counts and ragged/empty/all-masked shards (hypothesis property,
-    runs under the conftest fallback shim when hypothesis is absent);
+    shard counts and ragged/empty/all-masked shards (hypothesis property);
   * per-slot lookahead invalidation: membership events (staggered
     admission, retrieval splice) PATCH the affected rows instead of
     discarding the pending lookahead — cold starts stay at 1 per fallback

@@ -18,7 +18,7 @@ Load-bearing properties:
     assert;
   * the unified LSE-merge core matches the single-device paged attention
     for duplicate-free page ids with ``-1`` holes anywhere and ragged
-    per-slot lengths (hypothesis property, shim-compatible), through the
+    per-slot lengths (hypothesis property), through the
     ONE shard body the dense wrapper shares.
 
 CI runs this file under 2 host devices (the fast split) and in the
