@@ -46,9 +46,8 @@ def main():
                                  cfg.vocab_size)
     out = eng.generate(prompts, max_new=8)
     print("generated tokens:\n", out)
-    print(f"prefill {eng.stats['prefill_s']*1e3:.1f}ms, "
-          f"decode {eng.stats['decode_s']*1e3:.1f}ms "
-          f"({eng.stats['tokens']} tokens)")
+    print(f"{eng.stats['decode_steps']} decode steps, "
+          f"{eng.stats['tokens']} tokens")
 
 
 if __name__ == "__main__":

@@ -56,19 +56,21 @@ def dsa_init(key, cfg: ArchConfig, mem: MemoryConfig, stacked: bool = True):
     return p if stacked else jax.tree.map(lambda a: a[0], p)
 
 
-def _index_qkw(sp: Params, q: jnp.ndarray, k_cache: jnp.ndarray,
-               mem: MemoryConfig):
-    """prepare: q [B,1orHp,hd...] flattened; k_cache [B,S,KV,hd] -> index
-    tensors (q_idx [B,Hi,di], k_idx [B,S,di], w [B,Hi])."""
+def _index_q(sp: Params, q: jnp.ndarray):
+    """Query half of the lightning indexer (relevancy): q [B, Hp, hd] ->
+    (q_idx [B, Hi, di], w [B, Hi]); TP dead-head padding is cut off."""
     B = q.shape[0]
-    S = k_cache.shape[1]
-    qf = q.reshape(B, -1)
-    n_in = sp["wq_idx"].shape[0]
-    qf = qf[:, :n_in]
+    qf = q.reshape(B, -1)[:, : sp["wq_idx"].shape[0]]
     q_idx = (qf @ sp["wq_idx"]).reshape(B, -1, sp["wk_idx"].shape[1])
-    k_idx = k_cache.reshape(B, S, -1) @ sp["wk_idx"]
     w = jax.nn.softmax((qf.astype(jnp.float32) @ sp["w_wgt"]), axis=-1)
-    return q_idx, k_idx, w
+    return q_idx, w
+
+
+def _index_k(sp: Params, k_cache: jnp.ndarray):
+    """Key half of the lightning indexer (prepare): k_cache [B, S, KV, hd]
+    -> k_idx [B, S, di]."""
+    B, S = k_cache.shape[:2]
+    return k_cache.reshape(B, S, -1) @ sp["wk_idx"]
 
 
 def strip_dead_heads(q: jnp.ndarray, cfg: ArchConfig):
@@ -95,26 +97,30 @@ def make_sparse_fn(cfg: ArchConfig, mem: MemoryConfig, *, tp: int = 16,
     n_pages_sel = max(mem.top_k // page, 1)
 
     def sparse_fn(q, kc, vc, length, sp, k_new=None):
-        B, _, HP, hd = q.shape
+        B = q.shape[0]
         S = kc.shape[1]
-        # --- prepare (index projection of query + cached keys) ---
-        q_idx, k_idx, w = _index_qkw(sp, q[:, 0], kc, mem)
-        # --- fused relevancy + retrieve (Pallas kernel) ---
-        # page-level scores: max-pool token scores to micro-pages via
-        # scoring pooled keys (mean-pooled index vectors per page)
-        kp = k_idx.reshape(B, S // page, page, -1).mean(axis=2)
-        vals, pidx = ops.relevancy_topk(
-            q_idx, kp, w, n_pages_sel,
-            block=max(min(4096, S // page), n_pages_sel))
-        # mask pages beyond the live context (length is [] or per-slot [B])
-        lb = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
-        live = pidx * page < lb[:, None]
-        pidx = jnp.where(live, pidx, -1)
-        # --- apply: paged sparse attention over retrieved pages ---
-        out, _ = ops.paged_decode_attention(
-            strip_dead_heads(q, cfg), kc, vc, pidx.astype(jnp.int32), lb,
-            page_size=page)
-        return repad_dead_heads(out, q, cfg)  # [B,1,Hp,hd]
+        with jax.named_scope("prepare"):
+            # index projection of the cached keys, mean-pooled to
+            # micro-pages (page-level scores score the pooled keys)
+            kp = _index_k(sp, kc).reshape(B, S // page, page, -1).mean(axis=2)
+        with jax.named_scope("relevancy"):
+            # the Pallas kernel fuses relevancy with the top-k retrieval;
+            # its time counts here
+            q_idx, w = _index_q(sp, q[:, 0])
+            vals, pidx = ops.relevancy_topk(
+                q_idx, kp, w, n_pages_sel,
+                block=max(min(4096, S // page), n_pages_sel))
+        with jax.named_scope("retrieve"):
+            # mask pages beyond the live context (length [] or per-slot [B])
+            lb = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
+            live = pidx * page < lb[:, None]
+            pidx = jnp.where(live, pidx, -1)
+        with jax.named_scope("apply"):
+            # paged sparse attention over the retrieved pages
+            out, _ = ops.paged_decode_attention(
+                strip_dead_heads(q, cfg), kc, vc, pidx.astype(jnp.int32), lb,
+                page_size=page)
+            return repad_dead_heads(out, q, cfg)  # [B,1,Hp,hd]
 
     return sparse_fn
 
@@ -133,8 +139,8 @@ def make_sparse_fn_distributed(cfg: ArchConfig, mem: MemoryConfig, mesh, *,
     def sparse_fn(q, kc, vc, length, sp, k_new=None):
         B = q.shape[0]
         S = kc.shape[1]
-        q_idx, k_idx, w = _index_qkw(sp, q[:, 0], kc, mem)
-        kp = k_idx.reshape(B, S // page, page, -1).mean(axis=2)
+        q_idx, w = _index_q(sp, q[:, 0])
+        kp = _index_k(sp, kc).reshape(B, S // page, page, -1).mean(axis=2)
         vals, pidx = distributed_relevancy_topk(
             q_idx, kp, w, n_pages_sel, mesh, axis, block=2048,
             batch_axis=batch_axis)
@@ -188,9 +194,7 @@ def make_sparse_fn_cached(cfg: ArchConfig, mem: MemoryConfig, mesh, *,
         kidx_sum = sharded_page_add(kidx_sum, k_idx_new, (length - 1) // page,
                                     mesh, axis, batch_axis=batch_axis)
         # --- relevancy over the compressed pooled index ---
-        qf = q[:, 0].reshape(B, -1)[:, : p["wq_idx"].shape[0]]
-        q_idx = (qf @ p["wq_idx"]).reshape(B, -1, p["wk_idx"].shape[1])
-        w = jax.nn.softmax(qf.astype(jnp.float32) @ p["w_wgt"], axis=-1)
+        q_idx, w = _index_q(p, q[:, 0])
         n_pages = kidx_sum.shape[1]
         counts = jnp.clip(length - jnp.arange(n_pages) * page, 0, page)
         kp = kidx_sum * (1.0 / jnp.maximum(counts, 1))[None, :, None]
@@ -224,14 +228,10 @@ def build_pipeline(cfg: ArchConfig, mem: MemoryConfig, sp: Params, *,
     def prepare(M):
         kc, vc = M
         B, S = kc.shape[0], kc.shape[1]
-        k_idx = kc.reshape(B, S, -1) @ sp["wk_idx"]
-        return k_idx.reshape(B, S // page, page, -1).mean(axis=2)  # pooled
+        return _index_k(sp, kc).reshape(B, S // page, page, -1).mean(axis=2)
 
     def relevancy(kp, q):
-        B = q.shape[0]
-        qf = q[:, 0].reshape(B, -1)[:, : sp["wq_idx"].shape[0]]
-        q_idx = (qf @ sp["wq_idx"]).reshape(B, -1, sp["wk_idx"].shape[1])
-        w = jax.nn.softmax(qf.astype(jnp.float32) @ sp["w_wgt"], axis=-1)
+        q_idx, w = _index_q(sp, q[:, 0])
         if fused:
             vals, pidx = ops.relevancy_topk(
                 q_idx, kp, w, n_pages_sel,

@@ -51,25 +51,29 @@ def make_sparse_fn(cfg: ArchConfig, mem: MemoryConfig, *, tp: int = 16):
     def sparse_fn(q, kc, vc, length, sp, k_new=None):
         B = q.shape[0]
         S = kc.shape[1]
-        # prepare: page min/max pooling (Pallas kernel)
-        pmin, pmax = ops.page_minmax(kc, page_size=ps)
-        pmin = pmin.max(axis=2)  # reduce kv-head dim for the bound
-        pmax = pmax.max(axis=2)
-        # relevancy (bound) + retrieve top physical pages
-        sc = _physical_scores(q[:, 0], pmin[:, :, None], pmax[:, :, None], ppp)
-        n_sel = min(n_phys_sel, sc.shape[1])  # small caches: select them all
-        _, phys = jax.lax.top_k(sc, n_sel)                 # [B, n_sel]
-        # expand to logical pages
-        logical = (phys[..., None] * ppp +
-                   jnp.arange(ppp)[None, None, :]).reshape(B, -1)
-        lb = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
-        live = (logical * ps < lb[:, None]) & (logical < S // ps)
-        logical = jnp.where(live, logical, -1)
+        with jax.named_scope("prepare"):
+            # page min/max pooling (Pallas kernel)
+            pmin, pmax = ops.page_minmax(kc, page_size=ps)
+            pmin = pmin.max(axis=2)  # reduce kv-head dim for the bound
+            pmax = pmax.max(axis=2)
+        with jax.named_scope("relevancy"):
+            sc = _physical_scores(q[:, 0], pmin[:, :, None], pmax[:, :, None],
+                                  ppp)
+        with jax.named_scope("retrieve"):
+            # top physical pages, expanded to logical pages
+            n_sel = min(n_phys_sel, sc.shape[1])  # small caches: all of them
+            _, phys = jax.lax.top_k(sc, n_sel)                 # [B, n_sel]
+            logical = (phys[..., None] * ppp +
+                       jnp.arange(ppp)[None, None, :]).reshape(B, -1)
+            lb = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
+            live = (logical * ps < lb[:, None]) & (logical < S // ps)
+            logical = jnp.where(live, logical, -1)
         from repro.core.methods.dsa import strip_dead_heads, repad_dead_heads
-        out, _ = ops.paged_decode_attention(
-            strip_dead_heads(q, cfg), kc, vc, logical.astype(jnp.int32), lb,
-            page_size=ps)
-        return repad_dead_heads(out, q, cfg)
+        with jax.named_scope("apply"):
+            out, _ = ops.paged_decode_attention(
+                strip_dead_heads(q, cfg), kc, vc, logical.astype(jnp.int32),
+                lb, page_size=ps)
+            return repad_dead_heads(out, q, cfg)
 
     return sparse_fn
 

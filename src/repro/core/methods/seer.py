@@ -55,26 +55,30 @@ def make_sparse_fn(cfg: ArchConfig, mem: MemoryConfig, *, tp: int = 16):
     def sparse_fn(q, kc, vc, length, sp, k_new=None):
         B = q.shape[0]
         S = kc.shape[1]
-        # prepare: pooled block keys + gated query
-        k_gate = (kc.reshape(B, S, -1) @ sp["wk_gate"])
-        k_blk = k_gate.reshape(B, S // bs, bs, -1).mean(axis=2)  # [B,nb,di]
-        q_gate = (q[:, 0].reshape(B, -1) @ sp["wq_gate"])[:, None, :]  # [B,1,di]
-        w = jnp.ones((B, 1), jnp.float32)
-        # fused relevancy + retrieve (top-k blocks)
-        vals, bidx = ops.relevancy_topk(
-            q_gate, k_blk, w, n_sel, block=max(min(4096, S // bs), n_sel))
-        lb = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
-        live = bidx * bs < lb[:, None]
-        if mem.selection == "threshold":
-            # normalize: block softmax over selected candidates, drop < tau
-            probs = jax.nn.softmax(vals, axis=-1)
-            live &= probs >= mem.threshold
-        bidx = jnp.where(live, bidx, -1)
+        with jax.named_scope("prepare"):
+            # pooled block keys
+            k_gate = (kc.reshape(B, S, -1) @ sp["wk_gate"])
+            k_blk = k_gate.reshape(B, S // bs, bs, -1).mean(axis=2)
+        with jax.named_scope("relevancy"):
+            # gated query; the kernel fuses the top-k blocks in
+            q_gate = (q[:, 0].reshape(B, -1) @ sp["wq_gate"])[:, None, :]
+            w = jnp.ones((B, 1), jnp.float32)
+            vals, bidx = ops.relevancy_topk(
+                q_gate, k_blk, w, n_sel, block=max(min(4096, S // bs), n_sel))
+        with jax.named_scope("retrieve"):
+            lb = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
+            live = bidx * bs < lb[:, None]
+            if mem.selection == "threshold":
+                # normalize: block softmax over selected candidates, drop < tau
+                probs = jax.nn.softmax(vals, axis=-1)
+                live &= probs >= mem.threshold
+            bidx = jnp.where(live, bidx, -1)
         from repro.core.methods.dsa import strip_dead_heads, repad_dead_heads
-        out, _ = ops.paged_decode_attention(
-            strip_dead_heads(q, cfg), kc, vc, bidx.astype(jnp.int32), lb,
-            page_size=bs)
-        return repad_dead_heads(out, q, cfg)
+        with jax.named_scope("apply"):
+            out, _ = ops.paged_decode_attention(
+                strip_dead_heads(q, cfg), kc, vc, bidx.astype(jnp.int32), lb,
+                page_size=bs)
+            return repad_dead_heads(out, q, cfg)
 
     return sparse_fn
 

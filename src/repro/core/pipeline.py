@@ -22,6 +22,9 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 
 STAGES = ("prepare", "relevancy", "retrieve", "apply")
+# The ``jax.named_scope`` names a decode step's ops carry: the four stages,
+# the step's K/V written into the cache, and the dense model around them.
+SCOPES = STAGES + ("kv_write", "dense")
 
 
 @dataclasses.dataclass
@@ -52,18 +55,19 @@ class MemoryPipeline:
         out = None
         for s, fn, covers in self.stages():
             t0 = time.perf_counter() if profiler else None
-            if s == "prepare":
-                I = fn(M)
-                res = I
-            elif s == "relevancy":
-                res = fn(I, x)
-                sel = res
-            elif s == "retrieve":
-                sel = fn(M, sel)
-                res = sel
-            else:
-                out = fn(sel, x)
-                res = out
+            with jax.named_scope(s):
+                if s == "prepare":
+                    I = fn(M)
+                    res = I
+                elif s == "relevancy":
+                    res = fn(I, x)
+                    sel = res
+                elif s == "retrieve":
+                    sel = fn(M, sel)
+                    res = sel
+                else:
+                    out = fn(sel, x)
+                    res = out
             if profiler:
                 res = jax.block_until_ready(res)
                 profiler.record(self.name, covers, time.perf_counter() - t0)

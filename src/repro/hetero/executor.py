@@ -53,6 +53,7 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig, MemoryConfig
 from repro.hetero import policy as hpolicy
@@ -155,15 +156,17 @@ class HeteroExecutor:
             # donation stays on under the mesh: the pool buffers are
             # committed replicated (engine._ensure_pool), so input and
             # output shardings match and XLA can update in place
-            self._apply_jits[n_pages_view] = jax.jit(
-                lambda p, tok, kp, vp, table, lengths, live, pidx:
-                M.decode_step_paged_presel(
+            def decode_paged_presel(p, tok, kp, vp, table, lengths, live,
+                                    pidx):
+                return M.decode_step_paged_presel(
                     p, cfg, tok,
                     {"k_pages": kp, "v_pages": vp, "page_table": table,
                      "lengths": lengths},
                     live, pidx, mem, page_size=ps, tp=sc.tp,
-                    page_attn=page_attn),
-                donate_argnums=(2, 3))
+                    page_attn=page_attn)
+
+            self._apply_jits[n_pages_view] = jax.jit(
+                decode_paged_presel, donate_argnums=(2, 3))
         return self._apply_jits[n_pages_view]
 
     def _span_fn(self, Bg: int, S: int):
@@ -553,9 +556,10 @@ class HeteroExecutor:
             # the exit lookahead is validated at its consumption (FUSED
             # pins), mid-window selections by the fused-vs-stepped oracle
             self._validate(pidx, pidx_inputs)
-        nsteps = int(jax.block_until_ready(outs["nsteps"]))
-        emits_np = np.asarray(outs["emits"])
-        offl_np = np.asarray(outs["offl"])[:nsteps]
+        with TraceAnnotation("engine.decode.sync"):
+            nsteps = int(jax.block_until_ready(outs["nsteps"]))
+            emits_np = np.asarray(outs["emits"])
+            offl_np = np.asarray(outs["offl"])[:nsteps]
         for _ in range(nsteps):
             self._tick()
         self._fused_state_down(outs["summary"], outs["qbuf"])

@@ -1,15 +1,11 @@
-"""Per-stage timeline of the hetero offload executor (paper Fig. 3-5).
+"""Counters of the hetero offload executor: steps and tokens, host-clock
+step walls (and, under the synchronous schedule, the select and apply phase
+walls it exposes), dynamic-fallback steps, lookahead health and fused
+windows.
 
-The synchronous two-phase schedule exposes the phase walls directly
-(select / apply / exchange); the overlapped schedule by construction hides
-the select phase under apply, so the profiler reports what is observable —
-per-step wall time and the apply wall — plus the analytic decomposition.
-
-Phase walls are attributed to the paper's four pipeline stages with the
-roofline stage costs (``placement.sparse_attention_stage_costs``) as
-weights: the select phase covers prepare+relevancy+retrieve, the apply
-phase covers apply+rest — the same fused-attribution convention
-``core.pipeline.StageProfiler`` uses for the fused kernel.
+Where the time goes stage by stage is read from the device trace: the
+decode programs' ops carry the ``prepare`` / ``relevancy`` / ``retrieve`` /
+``apply`` named scopes (core/pipeline.SCOPES).
 """
 from __future__ import annotations
 
@@ -17,10 +13,6 @@ import json
 from typing import Dict, Optional
 
 from repro.configs.base import ArchConfig, MemoryConfig
-from repro.core import placement
-
-SELECT_STAGES = ("prepare", "relevancy", "retrieve")
-APPLY_STAGES = ("apply", "rest")
 
 
 class HeteroProfiler:
@@ -79,36 +71,6 @@ class HeteroProfiler:
         self.fused_windows += 1
         self.fused_steps += n_steps
 
-    # -- Fig. 3-style decomposition ------------------------------------
-
-    def _weights(self) -> Dict[str, float]:
-        costs = placement.sparse_attention_stage_costs(
-            self.cfg, self.mem, max(self.max_context, 1))
-        return {s: c.seconds() for s, c in costs.items()}
-
-    def stage_seconds(self) -> Dict[str, float]:
-        """Measured phase walls apportioned to the four pipeline stages."""
-        w = self._weights()
-        out: Dict[str, float] = {}
-        for group, total in ((SELECT_STAGES, self.select_s),
-                             (APPLY_STAGES, self.apply_s)):
-            gw = sum(w[s] for s in group) or 1.0
-            for s in group:
-                out[s] = total * w[s] / gw
-        return out
-
-    def fractions(self) -> Dict[str, float]:
-        ss = self.stage_seconds()
-        tot = sum(ss.values()) or 1.0
-        return {s: v / tot for s, v in ss.items()}
-
-    def memory_fraction(self) -> float:
-        """Fraction of phase time in memory processing (everything but
-        'rest') — the paper's headline metric."""
-        ss = self.stage_seconds()
-        tot = sum(ss.values())
-        return (tot - ss.get("rest", 0.0)) / tot if tot else float("nan")
-
     # -- reporting ------------------------------------------------------
 
     def summary(self, ledger=None, **transfer_kw) -> Dict:
@@ -134,8 +96,6 @@ class HeteroProfiler:
         }
         if self.mode == "sync":
             d["select_s"] = self.select_s
-            d["stage_fractions"] = self.fractions()
-            d["memory_fraction"] = self.memory_fraction()
         else:
             d["select_hidden"] = True   # overlapped under apply
         if ledger is not None:

@@ -35,7 +35,9 @@ ascending index on shard-ordered candidates, the merged selection is
 bit-identical to the unsharded one.
 
 All functions are pure jnp so the executor can jit them once and pin them
-to the offload device via committed inputs.
+to the offload device via committed inputs. Their ops carry the inline
+path's stage names (``jax.named_scope``): ingest is ``prepare``, scoring
+``relevancy``, top-k and the candidate merge ``retrieve``.
 """
 from __future__ import annotations
 
@@ -129,6 +131,7 @@ def _sum_summary(key: str, weight: str, page: int, L: int, n_slots: int,
         return jnp.einsum("l...f,lfd->l...d", kf,
                           sp[weight]).astype(jnp.float32)
 
+    @jax.named_scope("prepare")
     def ingest(s, sp, k_new, pos, live):
         B = pos.shape[0]
         own = live & (pos >= tok_lo) & (pos < tok_hi)
@@ -136,6 +139,7 @@ def _sum_summary(key: str, weight: str, page: int, L: int, n_slots: int,
         pages = jnp.clip((pos - tok_lo) // page, 0, P - 1)
         return {key: s[key].at[:, jnp.arange(B), pages].add(c)}
 
+    @jax.named_scope("prepare")
     def ingest_span(s, sp, k_span, slot_ids, start, n_valid):
         S = k_span.shape[2]
         gpos = start[:, None] + jnp.arange(S)[None, :]           # [Bg, S]
@@ -166,6 +170,7 @@ def _dsa(cfg: ArchConfig, mem: MemoryConfig, page: int, n_slots: int,
     summary_init, reset, ingest, ingest_span = _sum_summary(
         "kidx_sum", "wk_idx", page, L, n_slots, P, di, tok_lo)
 
+    @jax.named_scope("relevancy")
     def select_partial(sp, s, q_layers, lengths):
         qf = _qf_layers(q_layers, n_in)
         q_idx = jnp.einsum("lbf,lfe->lbe", qf, sp["wq_idx"])
@@ -178,9 +183,11 @@ def _dsa(cfg: ArchConfig, mem: MemoryConfig, page: int, n_slots: int,
         scores = jnp.einsum("lbh,lbhp->lbp", w, jax.nn.relu(dots))
         scores = jnp.where(_win_mask(P, page, tok_lo, lengths), scores,
                            NEG_INF)
-        vals, idx = jax.lax.top_k(scores, n_part)
-        return vals, (idx + tok_lo // page).astype(jnp.int32)
+        with jax.named_scope("retrieve"):
+            vals, idx = jax.lax.top_k(scores, n_part)
+            return vals, (idx + tok_lo // page).astype(jnp.int32)
 
+    @jax.named_scope("retrieve")
     def finalize(vals, idx, lengths):
         top_v, top_i = merge_shard_topk(vals, idx, n_sel)
         return jnp.where(top_v > NEG_INF / 2, top_i, -1).astype(jnp.int32)
@@ -213,6 +220,7 @@ def _seer(cfg: ArchConfig, mem: MemoryConfig, n_slots: int,
     summary_init, reset, ingest, ingest_span = _sum_summary(
         "kgate_sum", "wk_gate", bs, L, n_slots, P, di, tok_lo)
 
+    @jax.named_scope("relevancy")
     def select_partial(sp, s, q_layers, lengths):
         qf = _qf_layers(q_layers, n_in)
         q_gate = jnp.einsum("lbf,lfd->lbd", qf,
@@ -222,9 +230,11 @@ def _seer(cfg: ArchConfig, mem: MemoryConfig, n_slots: int,
             jnp.einsum("lbd,lbpd->lbp", q_gate, k_blk))
         scores = jnp.where(_win_mask(P, bs, tok_lo, lengths), scores,
                            NEG_INF)
-        vals, idx = jax.lax.top_k(scores, n_part)
-        return vals, (idx + tok_lo // bs).astype(jnp.int32)
+        with jax.named_scope("retrieve"):
+            vals, idx = jax.lax.top_k(scores, n_part)
+            return vals, (idx + tok_lo // bs).astype(jnp.int32)
 
+    @jax.named_scope("retrieve")
     def finalize(vals, idx, lengths):
         top_v, top_i = merge_shard_topk(vals, idx, n_sel)
         out = jnp.where(top_v > NEG_INF / 2, top_i, -1)
@@ -273,6 +283,7 @@ def _lserve(cfg: ArchConfig, mem: MemoryConfig, n_slots: int,
         return {"pmin": s["pmin"].at[:, slot_ids].set(BIG),
                 "pmax": s["pmax"].at[:, slot_ids].set(-BIG)}
 
+    @jax.named_scope("prepare")
     def ingest(s, sp, k_new, pos, live):
         B = pos.shape[0]
         kf = k_new.astype(jnp.float32)
@@ -285,6 +296,7 @@ def _lserve(cfg: ArchConfig, mem: MemoryConfig, n_slots: int,
         return {"pmin": s["pmin"].at[:, b, pages].min(lo),
                 "pmax": s["pmax"].at[:, b, pages].max(hi)}
 
+    @jax.named_scope("prepare")
     def ingest_span(s, sp, k_span, slot_ids, start, n_valid):
         S = k_span.shape[2]
         kf = k_span.astype(jnp.float32)
@@ -298,6 +310,7 @@ def _lserve(cfg: ArchConfig, mem: MemoryConfig, n_slots: int,
         return {"pmin": s["pmin"].at[:, slot_ids[:, None], pages].min(lo),
                 "pmax": s["pmax"].at[:, slot_ids[:, None], pages].max(hi)}
 
+    @jax.named_scope("relevancy")
     def select_partial(sp, s, q_layers, lengths):
         # reduce the kv-head axis for the bound (same as the inline path)
         pmin = s["pmin"].max(axis=3)                       # [L, B, P, hd]
@@ -308,9 +321,11 @@ def _lserve(cfg: ArchConfig, mem: MemoryConfig, n_slots: int,
         sc = pm.sum(-1).mean(axis=2)                       # [L, B, P]
         sc = jnp.where(_win_mask(P, ps, tok_lo, lengths), sc, NEG_INF)
         phys = sc.reshape(*sc.shape[:2], Pphys, ppp).max(-1)
-        vals, pidx = jax.lax.top_k(phys, n_part)           # [L, B, n_part]
-        return vals, (pidx + tok_lo // (ps * ppp)).astype(jnp.int32)
+        with jax.named_scope("retrieve"):
+            vals, pidx = jax.lax.top_k(phys, n_part)       # [L, B, n_part]
+            return vals, (pidx + tok_lo // (ps * ppp)).astype(jnp.int32)
 
+    @jax.named_scope("retrieve")
     def finalize(vals, idx, lengths):
         top_v, top_i = merge_shard_topk(vals, idx, n_phys)
         logical = (top_i[..., None] * ppp + jnp.arange(ppp)

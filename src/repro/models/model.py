@@ -156,6 +156,38 @@ def _attn_out(lp: Params, out: jnp.ndarray, cfg: ArchConfig, tp: int):
     return out.reshape(B, Sq, HP * hd) @ lp["wo"]
 
 
+# Decode-path scopes (``jax.named_scope``): the memory-pipeline stages of
+# core/pipeline.STAGES, plus ``kv_write`` (the step's K/V into the cache) and
+# ``dense`` (embedding, norms, projections, MLP, lm_head). They only name the
+# ops in the HLO metadata; the compiled code is the same.
+
+
+def _qkv(lp: Params, x, cos, sin, cfg: ArchConfig, tp: int):
+    """Pre-attention norm and q/k/v projection of one layer."""
+    with jax.named_scope("dense"):
+        h = L.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
+        return A.project_qkv(lp["attn"], h, cos, sin, cfg, tp)
+
+
+def _out_mlp(lp: Params, x, attn, cfg: ArchConfig, tp: int):
+    """o-projection, residual and MLP (or MoE) of one layer."""
+    with jax.named_scope("dense"):
+        x = x + _attn_out(lp["attn"], attn, cfg, tp)
+        h = L.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
+        if cfg.n_experts:
+            y, _ = M.moe_apply(lp["moe"], h, cfg)
+        else:
+            y = L.mlp(lp["mlp"], h)
+        return x + y
+
+
+def _logits(params: Params, cfg: ArchConfig, x):
+    """Final norm and lm_head at the last position."""
+    with jax.named_scope("dense"):
+        x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
+        return last_logits(params, cfg, x)
+
+
 def _tf_layer_full(lp, x, cos, sin, cfg, tp):
     """Full-sequence transformer layer; returns (x, aux, (k, v, q))."""
     h = _sp_gather(L.rms_norm(lp["attn_norm"], x, cfg.norm_eps))
@@ -177,23 +209,18 @@ def _tf_layer_decode(lp, x, cos, sin, cfg, tp, kc, vc, length, sparse_fn=None,
 
     A stateful sparse_fn may return (attn, new_sparse_params) — the
     incremental index cache of the prepare-memory stage lives there."""
-    h = L.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
-    q, k, v = A.project_qkv(lp["attn"], h, cos, sin, cfg, tp)
-    kc = jax.lax.dynamic_update_slice(kc, k, (0, length, 0, 0))
-    vc = jax.lax.dynamic_update_slice(vc, v, (0, length, 0, 0))
+    q, k, v = _qkv(lp, x, cos, sin, cfg, tp)
+    with jax.named_scope("kv_write"):
+        kc = jax.lax.dynamic_update_slice(kc, k, (0, length, 0, 0))
+        vc = jax.lax.dynamic_update_slice(vc, v, (0, length, 0, 0))
     sp_new = sparse_params
     if sparse_fn is not None:
         res = sparse_fn(q, kc, vc, length + 1, sparse_params, k_new=k)
         attn, sp_new = res if isinstance(res, tuple) else (res, sparse_params)
     else:
-        attn = A.attention_decode(q, kc, vc, length + 1, cfg, tp=tp)
-    x = x + _attn_out(lp["attn"], attn, cfg, tp)
-    h = L.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
-    if cfg.n_experts:
-        y, _ = M.moe_apply(lp["moe"], h, cfg)
-    else:
-        y = L.mlp(lp["mlp"], h)
-    return x + y, kc, vc, sp_new
+        with jax.named_scope("apply"):
+            attn = A.attention_decode(q, kc, vc, length + 1, cfg, tp=tp)
+    return _out_mlp(lp, x, attn, cfg, tp), kc, vc, sp_new
 
 
 def _maybe_ckpt(fn, remat: bool):
@@ -454,8 +481,7 @@ def decode_step(params, cfg: ArchConfig, token, caches, *, tp: int = 16,
         else:
             (k_new, v_new), sp_new = ys, sparse_params
         caches = dict(caches, k=k_new, v=v_new, length=length + 1)
-        x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
-        logits = last_logits(params, cfg, x)
+        logits = _logits(params, cfg, x)
         return (logits, caches, sp_new) if stateful else (logits, caches)
 
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
@@ -506,7 +532,8 @@ def decode_step_paged(params, cfg: ArchConfig, token, pool, live, *,
     lengths = pool["lengths"]
     table = pool["page_table"]
     live = live.astype(bool)
-    x = L.embed(params["embed"], token[:, None])
+    with jax.named_scope("dense"):
+        x = L.embed(params["embed"], token[:, None])
     positions = lengths[:, None]                           # [B, 1] per-slot
     if cfg.rope_style == "mrope" and positions3 is None:
         positions3 = jnp.broadcast_to(lengths[None, :, None], (3, B, 1))
@@ -514,24 +541,20 @@ def decode_step_paged(params, cfg: ArchConfig, token, pool, live, *,
 
     def layer_fn(x, lp_kv):
         lp, kp, vp, sp = lp_kv
-        h = L.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
-        q, k, v = A.project_qkv(lp["attn"], h, cos, sin, cfg, tp)
-        kp = pool_scatter_token(kp, table, lengths, k[:, 0], live)
-        vp = pool_scatter_token(vp, table, lengths, v[:, 0], live)
-        kc = pool_gather(kp, table)
-        vc = pool_gather(vp, table)
+        q, k, v = _qkv(lp, x, cos, sin, cfg, tp)
+        with jax.named_scope("kv_write"):
+            kp = pool_scatter_token(kp, table, lengths, k[:, 0], live)
+            vp = pool_scatter_token(vp, table, lengths, v[:, 0], live)
+        with jax.named_scope("retrieve"):
+            kc = pool_gather(kp, table)
+            vc = pool_gather(vp, table)
         if sparse_fn is not None:
             res = sparse_fn(q, kc, vc, lengths + 1, sp, k_new=k)
             attn = res[0] if isinstance(res, tuple) else res
         else:
-            attn = A.attention_decode(q, kc, vc, lengths + 1, cfg, tp=tp)
-        x = x + _attn_out(lp["attn"], attn, cfg, tp)
-        h = L.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
-        if cfg.n_experts:
-            y, _ = M.moe_apply(lp["moe"], h, cfg)
-        else:
-            y = L.mlp(lp["mlp"], h)
-        return x + y, (kp, vp)
+            with jax.named_scope("apply"):
+                attn = A.attention_decode(q, kc, vc, lengths + 1, cfg, tp=tp)
+        return _out_mlp(lp, x, attn, cfg, tp), (kp, vp)
 
     sp_stack = sparse_params
     if sp_stack is None:
@@ -539,10 +562,9 @@ def decode_step_paged(params, cfg: ArchConfig, token, pool, live, *,
     x, (k_new, v_new) = jax.lax.scan(
         layer_fn, x, (params["layers"], pool["k_pages"], pool["v_pages"],
                       sp_stack))
-    x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     pool = dict(pool, k_pages=k_new, v_pages=v_new,
                 lengths=lengths + live.astype(jnp.int32))
-    return last_logits(params, cfg, x), pool
+    return _logits(params, cfg, x), pool
 
 
 def decode_step_paged_presel(params, cfg: ArchConfig, token, pool, live,
@@ -591,7 +613,8 @@ def decode_step_paged_presel(params, cfg: ArchConfig, token, pool, live,
     lengths = pool["lengths"]
     table = pool["page_table"]
     live = live.astype(bool)
-    x = L.embed(params["embed"], token[:, None])
+    with jax.named_scope("dense"):
+        x = L.embed(params["embed"], token[:, None])
     positions = lengths[:, None]
     positions3 = None
     if cfg.rope_style == "mrope":
@@ -604,51 +627,50 @@ def decode_step_paged_presel(params, cfg: ArchConfig, token, pool, live,
 
     def layer_fn(x, lp_kv):
         lp, kp, vp, sel = lp_kv
-        h = L.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
-        q, k, v = A.project_qkv(lp["attn"], h, cos, sin, cfg, tp)
-        kp = pool_scatter_token(kp, table, lengths, k[:, 0], live)
-        vp = pool_scatter_token(vp, table, lengths, v[:, 0], live)
-        kc = pool_gather(kp, table)
-        vc = pool_gather(vp, table)
+        q, k, v = _qkv(lp, x, cos, sin, cfg, tp)
+        with jax.named_scope("kv_write"):
+            kp = pool_scatter_token(kp, table, lengths, k[:, 0], live)
+            vp = pool_scatter_token(vp, table, lengths, v[:, 0], live)
+        with jax.named_scope("retrieve"):
+            kc = pool_gather(kp, table)
+            vc = pool_gather(vp, table)
 
         def sparse(_):
-            s = jnp.where(sel == cur_page[:, None], -1, sel)   # dedup recency
-            s = jnp.where(s * ps < lb[:, None], s, -1)         # validity mask
-            s_full = jnp.concatenate([s, cur_page[:, None]], axis=1)
-            attn_fn = page_attn or ops.paged_decode_attention
-            out, _ = attn_fn(
-                strip_dead_heads(q, cfg), kc, vc, s_full.astype(jnp.int32),
-                lb, page_size=ps)
-            return repad_dead_heads(out, q, cfg)
+            with jax.named_scope("retrieve"):
+                s = jnp.where(sel == cur_page[:, None], -1, sel)  # dedup
+                s = jnp.where(s * ps < lb[:, None], s, -1)  # validity mask
+                s_full = jnp.concatenate([s, cur_page[:, None]], axis=1)
+            with jax.named_scope("apply"):
+                attn_fn = page_attn or ops.paged_decode_attention
+                out, _ = attn_fn(
+                    strip_dead_heads(q, cfg), kc, vc,
+                    s_full.astype(jnp.int32), lb, page_size=ps)
+                return repad_dead_heads(out, q, cfg)
 
         def dense(_):
-            if page_attn is None:
-                return A.attention_decode(q, kc, vc, lb, cfg, tp=tp)
-            # distributed dense fallback: all view pages selected through
-            # the same sequence-parallel seam (lb masks the live region)
-            n_pages = kc.shape[1] // ps
-            allp = jnp.broadcast_to(
-                jnp.arange(n_pages, dtype=jnp.int32)[None], (B, n_pages))
-            out, _ = page_attn(strip_dead_heads(q, cfg), kc, vc, allp, lb,
-                               page_size=ps)
-            return repad_dead_heads(out, q, cfg)
+            with jax.named_scope("apply"):
+                if page_attn is None:
+                    return A.attention_decode(q, kc, vc, lb, cfg, tp=tp)
+                # distributed dense fallback: all view pages selected
+                # through the same sequence-parallel seam (lb masks the
+                # live region)
+                n_pages = kc.shape[1] // ps
+                allp = jnp.broadcast_to(
+                    jnp.arange(n_pages, dtype=jnp.int32)[None], (B, n_pages))
+                out, _ = page_attn(strip_dead_heads(q, cfg), kc, vc, allp,
+                                   lb, page_size=ps)
+                return repad_dead_heads(out, q, cfg)
 
         attn = jax.lax.cond(use_sparse, sparse, dense, None)
-        x = x + _attn_out(lp["attn"], attn, cfg, tp)
-        h = L.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
-        if cfg.n_experts:
-            y, _ = M.moe_apply(lp["moe"], h, cfg)
-        else:
-            y = L.mlp(lp["mlp"], h)
-        return x + y, (kp, vp, q[:, 0], k[:, 0])
+        return (_out_mlp(lp, x, attn, cfg, tp),
+                (kp, vp, q[:, 0], k[:, 0]))
 
     x, (k_new, v_new, q_layers, k_layers) = jax.lax.scan(
         layer_fn, x, (params["layers"], pool["k_pages"], pool["v_pages"],
                       pidx))
-    x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     pool = dict(pool, k_pages=k_new, v_pages=v_new,
                 lengths=lengths + live.astype(jnp.int32))
-    return last_logits(params, cfg, x), pool, q_layers, k_layers
+    return _logits(params, cfg, x), pool, q_layers, k_layers
 
 
 def extend_paged(params, cfg: ArchConfig, tokens, pool, n_valid, *,
@@ -679,9 +701,11 @@ def extend_paged(params, cfg: ArchConfig, tokens, pool, n_valid, *,
     B, C = tokens.shape
     lengths = pool["lengths"]
     table = pool["page_table"]
-    x = L.embed(params["embed"], tokens)
-    if x_embeds is not None:
-        x = jnp.where(emb_rows[:, None, None], x_embeds.astype(x.dtype), x)
+    with jax.named_scope("dense"):
+        x = L.embed(params["embed"], tokens)
+        if x_embeds is not None:
+            x = jnp.where(emb_rows[:, None, None], x_embeds.astype(x.dtype),
+                          x)
     positions = lengths[:, None] + jnp.arange(C)[None, :]  # [B, C]
     positions3 = None
     if cfg.rope_style == "mrope":
@@ -690,28 +714,26 @@ def extend_paged(params, cfg: ArchConfig, tokens, pool, n_valid, *,
 
     def layer_fn(x, lp_kv):
         lp, kp, vp = lp_kv
-        h = L.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
-        q, k, v = A.project_qkv(lp["attn"], h, cos, sin, cfg, tp)
-        kp = pool_scatter_span(kp, table, lengths, k, n_valid)
-        vp = pool_scatter_span(vp, table, lengths, v, n_valid)
-        kc = pool_gather(kp, table)
-        vc = pool_gather(vp, table)
-        attn = A.attention_decode_chunk(q, kc, vc, lengths, cfg, tp=tp)
-        x = x + _attn_out(lp["attn"], attn, cfg, tp)
-        h = L.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
-        if cfg.n_experts:
-            y, _ = M.moe_apply(lp["moe"], h, cfg)
-        else:
-            y = L.mlp(lp["mlp"], h)
-        return x + y, ((kp, vp, k, q) if collect_kq else (kp, vp))
+        q, k, v = _qkv(lp, x, cos, sin, cfg, tp)
+        with jax.named_scope("kv_write"):
+            kp = pool_scatter_span(kp, table, lengths, k, n_valid)
+            vp = pool_scatter_span(vp, table, lengths, v, n_valid)
+        with jax.named_scope("retrieve"):
+            kc = pool_gather(kp, table)
+            vc = pool_gather(vp, table)
+        with jax.named_scope("apply"):
+            attn = A.attention_decode_chunk(q, kc, vc, lengths, cfg, tp=tp)
+        x = _out_mlp(lp, x, attn, cfg, tp)
+        return x, ((kp, vp, k, q) if collect_kq else (kp, vp))
 
     x, ys = jax.lax.scan(
         layer_fn, x, (params["layers"], pool["k_pages"], pool["v_pages"]))
     k_new, v_new = ys[0], ys[1]
-    x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     last = jnp.clip(n_valid - 1, 0, C - 1)
-    xg = jnp.take_along_axis(x, last[:, None, None], axis=1)   # [B, 1, d]
-    logits = L.lm_head(params["lm_head"], xg, cfg)[:, 0]
+    with jax.named_scope("dense"):
+        x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
+        xg = jnp.take_along_axis(x, last[:, None, None], axis=1)  # [B,1,d]
+        logits = L.lm_head(params["lm_head"], xg, cfg)[:, 0]
     pool = dict(pool, k_pages=k_new, v_pages=v_new, lengths=lengths + n_valid)
     if not collect_kq:
         return logits, pool

@@ -34,6 +34,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig, MemoryConfig
 from repro.core import placement
@@ -44,6 +45,17 @@ from repro.serving.events import StepEvents
 from repro.serving.kv_cache import PagedKVPool, SlotManager
 
 POOL_FAMILIES = ("dense", "moe", "audio", "vlm")
+
+
+# Host spans of one serving turn (``jax.profiler.TraceAnnotation``, on the
+# device trace's clock; well under a microsecond each with the profiler off):
+#   engine.poll                the whole turn, holding
+#     engine.admit             queue admission (and its bucketed prefill)
+#     engine.prefill           one chunk of chunked prefill
+#     engine.decode.launch     page-table view, argument upload, the jit call
+#     engine.decode.sync       the step's tokens pulled to the host
+#     engine.decode.emit       emissions, slot and page bookkeeping, retrieval
+#     engine.dispatch          tokens handed to their ResponseHandles
 
 
 def _next_pow2(n: int) -> int:
@@ -331,31 +343,31 @@ class Engine:
                 cfg, self.sc, sc.retrieval, self.params, key=key,
                 devices=rdevs)
 
-        self._prefill = jax.jit(
-            lambda p, toks: M.prefill(p, cfg, toks, max_len=sc.max_len,
-                                      tp=sc.tp),
-        )
-        self._decode = jax.jit(
-            lambda p, tok, caches, sp: M.decode_step(
-                p, cfg, tok, caches, tp=sc.tp,
-                sparse_fn=self._sparse_fn,
-                sparse_params=sp),
-        )
+        # named functions, not lambdas: the compiled programs are called
+        # jit_<name> in profiles and compile logs
+        def prefill(p, toks):
+            return M.prefill(p, cfg, toks, max_len=sc.max_len, tp=sc.tp)
+
+        def decode(p, tok, caches, sp):
+            return M.decode_step(p, cfg, tok, caches, tp=sc.tp,
+                                 sparse_fn=self._sparse_fn, sparse_params=sp)
+
+        def decode_paged(p, tok, kp, vp, table, lengths, live, sp):
+            return M.decode_step_paged(
+                p, cfg, tok,
+                {"k_pages": kp, "v_pages": vp, "page_table": table,
+                 "lengths": lengths},
+                live, tp=sc.tp, sparse_fn=self._sparse_fn, sparse_params=sp)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode)
         # pooled-path jits (built lazily; bucket/chunk shapes cached by key).
         # k_pages/v_pages are DONATED: the engine replaces its references
         # with the outputs right after each call, so XLA may update the pool
         # in place instead of copying the whole arena every token (on CPU
         # donation is a no-op warning; on TPU it is the difference between
         # O(touched pages) and O(pool) per-step HBM traffic).
-        self._decode_paged = jax.jit(
-            lambda p, tok, kp, vp, table, lengths, live, sp:
-            M.decode_step_paged(
-                p, cfg, tok,
-                {"k_pages": kp, "v_pages": vp, "page_table": table,
-                 "lengths": lengths},
-                live, tp=sc.tp,
-                sparse_fn=self._sparse_fn, sparse_params=sp),
-            donate_argnums=(2, 3))
+        self._decode_paged = jax.jit(decode_paged, donate_argnums=(2, 3))
         self._bucket_fns: Dict[Tuple[int, int], callable] = {}
         self._extend_fns: Dict[Tuple[int, bool], callable] = {}
         self._splice_fns: Dict[Tuple[int, int], callable] = {}
@@ -370,8 +382,7 @@ class Engine:
         # host_steps counts step_pool dispatch boundaries, decode_steps the
         # device steps behind them — their ratio is the host-dispatch
         # amortization a fused window buys (bench_fused_decode)
-        self.stats = {"prefill_s": 0.0, "decode_s": 0.0, "tokens": 0,
-                      "host_steps": 0, "decode_steps": 0}
+        self.stats = {"tokens": 0, "host_steps": 0, "decode_steps": 0}
 
         # --- request-level admission state (api.Request is the ONE way
         # into the pool; the compatibility Scheduler and the fleet router
@@ -478,13 +489,19 @@ class Engine:
         chunked prefill, run one pooled-decode dispatch, and route the
         emissions into their handles. The fleet router and ``drain`` both
         pump this; it is safe to call on an idle engine."""
-        self._ensure_pool()
-        self._admit_from_queue()
-        self._polled_prefill = bool(self.has_prefill_work()
-                                    and self.prefill_step())
-        ev = self.step_pool()
-        self._dispatch(ev)
-        return ev
+        with TraceAnnotation("engine.poll"):
+            self._ensure_pool()
+            if self.queue:
+                with TraceAnnotation("engine.admit"):
+                    self._admit_from_queue()
+            self._polled_prefill = False
+            if self.has_prefill_work():
+                with TraceAnnotation("engine.prefill"):
+                    self._polled_prefill = self.prefill_step()
+            ev = self.step_pool()
+            with TraceAnnotation("engine.dispatch"):
+                self._dispatch(ev)
+            return ev
 
     def drain(self, max_steps: int = 10_000) -> Dict[int, ResponseHandle]:
         """Pump ``poll`` until queue and pool are empty (or the head
@@ -550,20 +567,14 @@ class Engine:
     def _generate_batched(self, prompts: jnp.ndarray,
                           max_new: int) -> np.ndarray:
         """Legacy batched dense-cache loop (the pre-pool oracle)."""
-        t0 = time.perf_counter()
-        logits, caches = jax.block_until_ready(
-            self._prefill(self.params, prompts))
-        self.stats["prefill_s"] += time.perf_counter() - t0
+        logits, caches = self._prefill(self.params, prompts)
         tok = jnp.argmax(logits, -1).astype(jnp.int32)
         out = []
-        t0 = time.perf_counter()
         for _ in range(max_new):
             out.append(tok)
             logits, caches = self._decode(self.params, tok, caches,
                                           self.sparse_params)
             tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        jax.block_until_ready(tok)
-        self.stats["decode_s"] += time.perf_counter() - t0
         self.stats["tokens"] += int(prompts.shape[0]) * max_new
         return np.stack([np.asarray(t) for t in out], axis=1)
 
@@ -610,10 +621,12 @@ class Engine:
         if key not in self._bucket_fns:
             cfg, sc = self.cfg, self.sc
             cq = self.hetero is not None
-            self._bucket_fns[key] = jax.jit(
-                lambda p, toks, lens: M.prefill_bucketed(p, cfg, toks, lens,
-                                                         tp=sc.tp,
-                                                         collect_q=cq))
+
+            def prefill_bucket(p, toks, lens):
+                return M.prefill_bucketed(p, cfg, toks, lens, tp=sc.tp,
+                                          collect_q=cq)
+
+            self._bucket_fns[key] = jax.jit(prefill_bucket)
         return self._bucket_fns[key]
 
     def _get_splice_fn(self, B: int, n_pages: int):
@@ -663,10 +676,8 @@ class Engine:
                     slot, prompt,
                     retrieval[i] if retrieval is not None else None)
         ok.extend([False] * (len(requests) - len(ok)))
-        t0 = time.perf_counter()
         for Sb, group in admitted.items():
             self._prefill_bucket(Sb, group)
-        self.stats["prefill_s"] += time.perf_counter() - t0
         return ok
 
     def _prefill_bucket(self, Sb: int, group: List[Tuple[int, np.ndarray]]):
@@ -754,22 +765,18 @@ class Engine:
         if key not in self._extend_fns:
             cfg, sc = self.cfg, self.sc
             ckq = self.hetero is not None
-            if embeds:
-                fn = lambda p, toks, kp, vp, table, lengths, nv, xe, er: \
-                    M.extend_paged(
-                        p, cfg, toks,
-                        {"k_pages": kp, "v_pages": vp, "page_table": table,
-                         "lengths": lengths},
-                        nv, tp=sc.tp, collect_kq=ckq, x_embeds=xe,
-                        emb_rows=er)
-            else:
-                fn = lambda p, toks, kp, vp, table, lengths, nv: \
-                    M.extend_paged(
-                        p, cfg, toks,
-                        {"k_pages": kp, "v_pages": vp, "page_table": table,
-                         "lengths": lengths},
-                        nv, tp=sc.tp, collect_kq=ckq)
-            self._extend_fns[key] = jax.jit(fn, donate_argnums=(2, 3))
+
+            def extend_paged(p, toks, kp, vp, table, lengths, nv, *emb):
+                # emb: (x_embeds, emb_rows) for the embedding-splice variant
+                xe, er = emb if embeds else (None, None)
+                return M.extend_paged(
+                    p, cfg, toks,
+                    {"k_pages": kp, "v_pages": vp, "page_table": table,
+                     "lengths": lengths},
+                    nv, tp=sc.tp, collect_kq=ckq, x_embeds=xe, emb_rows=er)
+
+            self._extend_fns[key] = jax.jit(extend_paged,
+                                            donate_argnums=(2, 3))
         return self._extend_fns[key]
 
     def prefill_step(self) -> bool:
@@ -798,7 +805,6 @@ class Engine:
             n_valid[slot] = take
         lengths = np.asarray([s.length for s in self.slots.slots], np.int32)
         lengths = np.where(n_valid > 0, lengths, 0)
-        t0 = time.perf_counter()
         table = self._table_view(lengths, extra=C)
         args = (self.params, jnp.asarray(toks), self.pool.device["k_pages"],
                 self.pool.device["v_pages"], table, jnp.asarray(lengths),
@@ -811,7 +817,6 @@ class Engine:
         logits, pool = out[0], out[1]
         self.pool.device["k_pages"] = pool["k_pages"]
         self.pool.device["v_pages"] = pool["v_pages"]
-        self.stats["prefill_s"] += time.perf_counter() - t0
         nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
         finished: List[int] = []     # slots whose payload (admission
         for slot in list(self._chunks):  # prompt or splice) completed
@@ -905,42 +910,44 @@ class Engine:
         K = self._fused_window()
         if K > 1:
             return self._step_pool_fused(live, K)
-        lengths = np.where(live, self.slots.lengths(), 0).astype(np.int32)
-        t0 = time.perf_counter()
-        table = self._table_view(lengths)
-        tok = jnp.asarray(self._pending)
-        if self.hetero is not None:
-            logits, pool = self.hetero.decode(
-                self.params, tok, self.pool.device, table, lengths, live)
-        else:
-            logits, pool = self._decode_paged(
-                self.params, tok, self.pool.device["k_pages"],
-                self.pool.device["v_pages"], table, jnp.asarray(lengths),
-                jnp.asarray(live), self.sparse_params)
-        self.pool.device["k_pages"] = pool["k_pages"]
-        self.pool.device["v_pages"] = pool["v_pages"]
-        nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
-        self.stats["decode_s"] += time.perf_counter() - t0
-        self.stats["host_steps"] += 1
-        self.stats["decode_steps"] += 1
-        ev = StepEvents(steps=1)
-        for i in np.flatnonzero(live):
-            rid = self.slots.slots[i].request_id
-            ev.emissions.append((rid, int(i), int(self._pending[i])))
-            if self.retrieval is not None:
-                self.retrieval.note_token(int(i), int(self._pending[i]))
-            self._pending[i] = nxt[i]
-        self.stats["tokens"] += len(ev.emissions)
-        self.slots.step(live)
-        for i in np.flatnonzero(live):
-            if self.slots.slots[i].done:
-                ev.finished.append(int(i))
-                self.pool.release(int(i))
+        with TraceAnnotation("engine.decode.launch"):
+            lengths = np.where(live, self.slots.lengths(), 0).astype(
+                np.int32)
+            table = self._table_view(lengths)
+            tok = jnp.asarray(self._pending)
+            if self.hetero is not None:
+                logits, pool = self.hetero.decode(
+                    self.params, tok, self.pool.device, table, lengths, live)
+            else:
+                logits, pool = self._decode_paged(
+                    self.params, tok, self.pool.device["k_pages"],
+                    self.pool.device["v_pages"], table, jnp.asarray(lengths),
+                    jnp.asarray(live), self.sparse_params)
+            self.pool.device["k_pages"] = pool["k_pages"]
+            self.pool.device["v_pages"] = pool["v_pages"]
+        with TraceAnnotation("engine.decode.sync"):
+            nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
+        with TraceAnnotation("engine.decode.emit"):
+            self.stats["host_steps"] += 1
+            self.stats["decode_steps"] += 1
+            ev = StepEvents(steps=1)
+            for i in np.flatnonzero(live):
+                rid = self.slots.slots[i].request_id
+                ev.emissions.append((rid, int(i), int(self._pending[i])))
                 if self.retrieval is not None:
-                    self.retrieval.on_release(int(i))
-        if self.retrieval is not None:
-            ev.fired.extend(self._retrieval_step(logits, live, lengths))
-        return ev
+                    self.retrieval.note_token(int(i), int(self._pending[i]))
+                self._pending[i] = nxt[i]
+            self.stats["tokens"] += len(ev.emissions)
+            self.slots.step(live)
+            for i in np.flatnonzero(live):
+                if self.slots.slots[i].done:
+                    ev.finished.append(int(i))
+                    self.pool.release(int(i))
+                    if self.retrieval is not None:
+                        self.retrieval.on_release(int(i))
+            if self.retrieval is not None:
+                ev.fired.extend(self._retrieval_step(logits, live, lengths))
+            return ev
 
     # -- fused multi-step decode (serving/fused.py) ---------------------
 
@@ -963,11 +970,12 @@ class Engine:
                   table, jnp.asarray(lengths), jnp.asarray(live),
                   jnp.asarray(gen), jnp.asarray(maxnew),
                   jnp.asarray(armed), jnp.asarray(arm_after))
-        nsteps = int(jax.block_until_ready(outs["nsteps"]))
-        return {"k_pages": outs["k_pages"], "v_pages": outs["v_pages"],
-                "pending": outs["pending"], "nsteps": nsteps,
-                "emits": np.asarray(outs["emits"]),
-                "fired": np.asarray(outs["fired"])}
+        with TraceAnnotation("engine.decode.sync"):
+            nsteps = int(jax.block_until_ready(outs["nsteps"]))
+            return {"k_pages": outs["k_pages"], "v_pages": outs["v_pages"],
+                    "pending": outs["pending"], "nsteps": nsteps,
+                    "emits": np.asarray(outs["emits"]),
+                    "fired": np.asarray(outs["fired"])}
 
     def _step_pool_fused(self, live: np.ndarray, K: int) -> StepEvents:
         """Run up to K decode steps in one jitted scan, then replay the
@@ -990,55 +998,57 @@ class Engine:
             armed = np.zeros((self.sc.n_slots,), bool)
             arm_after = np.zeros((self.sc.n_slots,), np.int32)
             trigger = None
-        t0 = time.perf_counter()
-        # extra=K: mid-window lengths grow up to K past the entry maximum,
-        # and a page-table view is numerically neutral but a scatter
-        # outside it would silently drop — the view must cover the window
-        table = self._table_view(lengths, extra=K)
-        if self.hetero is not None:
-            res = self.hetero.decode_fused(
-                self.params, self._pending, self.pool.device, table,
-                lengths, live, K, gen_np=gen, maxnew_np=maxnew,
-                armed_np=armed, arm_after_np=arm_after, trigger=trigger)
-        else:
-            res = self._decode_fused_inline(table, lengths, live, K, gen,
-                                            maxnew, armed, arm_after,
-                                            trigger)
-        self.pool.device["k_pages"] = res["k_pages"]
-        self.pool.device["v_pages"] = res["v_pages"]
-        self._pending = np.asarray(res["pending"], np.int32).copy()
-        nsteps = res["nsteps"]
-        emits, fired = res["emits"], res["fired"]
-        self.stats["decode_s"] += time.perf_counter() - t0
-        self.stats["host_steps"] += 1
-        self.stats["decode_steps"] += nsteps
-        ev = StepEvents(steps=nsteps)
-        for j in range(nsteps):
-            step_live = emits[j] >= 0
-            for i in np.flatnonzero(step_live):
-                ev.emissions.append((sl[i].request_id, int(i),
-                                     int(emits[j, i])))
-                if rx is not None:
-                    rx.note_token(int(i), int(emits[j, i]))
-            self.stats["tokens"] += int(step_live.sum())
-            self.slots.step(step_live)
-            for i in np.flatnonzero(step_live):
-                if sl[i].done:
-                    ev.finished.append(int(i))
-                    self.pool.release(int(i))
+        # the window's device sync (engine.decode.sync) nests inside this
+        with TraceAnnotation("engine.decode.launch"):
+            # extra=K: mid-window lengths grow up to K past the entry
+            # maximum, and a page-table view is numerically neutral but a
+            # scatter outside it would silently drop — the view must cover
+            # the window
+            table = self._table_view(lengths, extra=K)
+            if self.hetero is not None:
+                res = self.hetero.decode_fused(
+                    self.params, self._pending, self.pool.device, table,
+                    lengths, live, K, gen_np=gen, maxnew_np=maxnew,
+                    armed_np=armed, arm_after_np=arm_after, trigger=trigger)
+            else:
+                res = self._decode_fused_inline(table, lengths, live, K, gen,
+                                                maxnew, armed, arm_after,
+                                                trigger)
+        with TraceAnnotation("engine.decode.emit"):
+            self.pool.device["k_pages"] = res["k_pages"]
+            self.pool.device["v_pages"] = res["v_pages"]
+            self._pending = np.asarray(res["pending"], np.int32).copy()
+            nsteps = res["nsteps"]
+            emits, fired = res["emits"], res["fired"]
+            self.stats["host_steps"] += 1
+            self.stats["decode_steps"] += nsteps
+            ev = StepEvents(steps=nsteps)
+            for j in range(nsteps):
+                step_live = emits[j] >= 0
+                for i in np.flatnonzero(step_live):
+                    ev.emissions.append((sl[i].request_id, int(i),
+                                         int(emits[j, i])))
                     if rx is not None:
-                        rx.on_release(int(i))
-            if rx is not None:
-                rx.tick()
-                for job in rx.collect_ready(min_age=1):
-                    self._queue_splice(*job)
-                for i in np.flatnonzero(fired[j]):
-                    if not self._reserve_splice(int(i)):
-                        rx.note_suppressed(int(i))
-                        continue
-                    rx.launch(int(i))
-                    ev.fired.append(int(i))
-        return ev
+                        rx.note_token(int(i), int(emits[j, i]))
+                self.stats["tokens"] += int(step_live.sum())
+                self.slots.step(step_live)
+                for i in np.flatnonzero(step_live):
+                    if sl[i].done:
+                        ev.finished.append(int(i))
+                        self.pool.release(int(i))
+                        if rx is not None:
+                            rx.on_release(int(i))
+                if rx is not None:
+                    rx.tick()
+                    for job in rx.collect_ready(min_age=1):
+                        self._queue_splice(*job)
+                    for i in np.flatnonzero(fired[j]):
+                        if not self._reserve_splice(int(i)):
+                            rx.note_suppressed(int(i))
+                            continue
+                        rx.launch(int(i))
+                        ev.fired.append(int(i))
+            return ev
 
     # -- retrieval service hooks (src/repro/retrieval) ------------------
 

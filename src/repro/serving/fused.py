@@ -88,8 +88,8 @@ def make_fused_paged(cfg, mem, sc, *, K: int, trigger, sparse_fn):
     gen, maxnew, armed, arm_after) -> outs``; the engine jits it with
     the pool buffers donated."""
 
-    def fused(params, sp, tok, kp, vp, table, lengths, live, gen, maxnew,
-              armed, arm_after):
+    def fused_decode(params, sp, tok, kp, vp, table, lengths, live, gen,
+                     maxnew, armed, arm_after):
         B = tok.shape[0]
 
         def idle(c):
@@ -120,7 +120,7 @@ def make_fused_paged(cfg, mem, sc, *, K: int, trigger, sparse_fn):
                 "pending": carry["pending"], "nsteps": carry["nsteps"],
                 "emits": emits, "fired": fired}
 
-    return fused
+    return fused_decode
 
 
 def make_fused_presel(cfg, mem, sc, sel, *, K: int, trigger, page_attn):
@@ -147,8 +147,9 @@ def make_fused_presel(cfg, mem, sc, sel, *, K: int, trigger, page_attn):
     the merged per-shard selection) and scatter it back after the window.
     """
 
-    def fused(params, sp, tok, kp, vp, table, lengths, live, gen, maxnew,
-              sel0, sel_ok0, summary0, qbuf0, armed, arm_after):
+    def fused_decode(params, sp, tok, kp, vp, table, lengths, live, gen,
+                     maxnew, sel0, sel_ok0, summary0, qbuf0, armed,
+                     arm_after):
         B = tok.shape[0]
         neg = jnp.full((cfg.n_layers, B, sel.n_sel), -1, jnp.int32)
 
@@ -214,4 +215,4 @@ def make_fused_presel(cfg, mem, sc, sel, *, K: int, trigger, page_attn):
                 "prev_q": carry["prev_q"], "prev_len": carry["prev_len"],
                 "emits": emits, "fired": fired, "offl": offl}
 
-    return fused
+    return fused_decode
