@@ -167,7 +167,8 @@ def distributed_paged_sparse_decode(
     broadcast and the view has no holes:
 
       * ``k_cache``/``v_cache`` are the gathered paged-pool view
-        (``kernels.page_pool.pool_gather`` over the slot's page table) —
+        (``kernels.page_pool.pool_gather`` of one layer over the slot's
+        page table) —
         positions outside a slot's live region are exact zeros by the
         pool's zero-page invariant, so cutting the view into sequence
         shards never exposes stale data;

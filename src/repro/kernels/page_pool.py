@@ -2,16 +2,20 @@
 
 Two groups of device code live here:
 
-* Paged-pool access (``pool_gather`` / ``pool_scatter_token`` /
-  ``pool_scatter_span``): the serving engine stores KV in a shared pool of
-  fixed-size physical pages ``[n_pages, page_size, KV, dh]`` and addresses it
-  through per-slot page tables, so HBM scales with *live* tokens instead of
-  ``n_slots * max_len``. On CPU/XLA the gather materializes a contiguous
-  per-slot view (advanced-indexing gather — XLA lowers it to a DMA-friendly
-  dynamic-gather); on TPU the paged Pallas kernel in
-  ``sparse_decode_attention.py`` consumes the page table directly via
-  scalar-prefetch block index maps, so the materialized view is never needed
-  on the sparse path.
+* Paged-pool access (``pool_gather`` / ``pool_scatter`` at the locations
+  ``token_dest`` / ``span_dest`` give): the serving engine stores KV in a
+  shared pool of fixed-size physical pages, stacked over layers as
+  ``[L, n_pages, page_size, KV, dh]``, and addresses it through per-slot
+  page tables, so HBM scales with *live* tokens instead of
+  ``n_slots * max_len``. The model's layer loop carries the whole stacked
+  pool and hands these helpers a layer index, which becomes part of the
+  gather's or scatter's indices: no ``[n_pages, page_size, KV, dh]`` slice
+  of a layer is ever materialized, and the scatter updates the carried
+  pool in place. ``pool_gather`` materializes a contiguous per-slot view of
+  one layer (an advanced-indexing gather); every decode path's attention
+  (the dense fallback and the paged Pallas kernel in
+  ``sparse_decode_attention.py``, which picks selected pages out of that
+  view) reads the view, not the pool.
 
 * ``page_minmax``: the LServe Prepare-Memory stage. Each logical page of the
   key cache is summarized by its channel-wise min and max vectors; the
@@ -32,61 +36,67 @@ from jax.experimental import pallas as pl
 # ---------------------------------------------------------------------------
 
 
-def pool_gather(pages: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
-    """Materialize contiguous per-slot views from the shared page pool.
+def pool_gather(pages: jnp.ndarray, layer, page_table: jnp.ndarray
+                ) -> jnp.ndarray:
+    """Materialize contiguous per-slot views of one layer of the pool.
 
-    pages [P, ps, KV, dh]; page_table [B, NP] int32 (physical page id per
-    logical page; unallocated entries point at the reserved zero page 0)
+    pages [L, P, ps, KV, dh]; layer: int32 scalar (may be traced);
+    page_table [B, NP] int32 (physical page id per logical page;
+    unallocated entries point at the reserved zero page 0)
     -> [B, NP * ps, KV, dh].
     """
-    P, ps, KV, dh = pages.shape
+    _, _, ps, KV, dh = pages.shape
     B, NP = page_table.shape
-    view = pages[page_table]                      # [B, NP, ps, KV, dh]
+    view = pages[layer, page_table]               # [B, NP, ps, KV, dh]
     return view.reshape(B, NP * ps, KV, dh)
 
 
-def pool_scatter_token(pages: jnp.ndarray, page_table: jnp.ndarray,
-                       positions: jnp.ndarray, values: jnp.ndarray,
-                       live: jnp.ndarray) -> jnp.ndarray:
-    """Write one new token per slot into the pool.
+def token_dest(page_table: jnp.ndarray, positions: jnp.ndarray,
+               live: jnp.ndarray, page_size: int):
+    """Where one new token per slot is written: ``(page, row, keep)``, each
+    [B], for ``pool_scatter``.
 
-    pages [P, ps, KV, dh]; page_table [B, NP]; positions [B] (logical token
-    position being written); values [B, KV, dh]; live [B] bool. Dead slots
-    write ZEROS to the reserved trash page 0 so the pool stays clean (the
-    zero page is part of every unallocated page-table entry and must remain
-    zero for pooled decode to match per-request decode exactly).
+    page_table [B, NP]; positions [B] (logical token position being
+    written); live [B] bool. Dead slots go to the reserved trash page 0 and
+    write zeros there (``keep`` False), so the pool stays clean: the zero
+    page is part of every unallocated page-table entry and must remain zero
+    for pooled decode to match per-request decode exactly. The location is
+    the same in every layer.
     """
-    ps = pages.shape[1]
-    B = positions.shape[0]
     NP = page_table.shape[1]
-    logical = jnp.clip(positions // ps, 0, NP - 1)  # dead slots can sit at NP
+    logical = jnp.clip(positions // page_size, 0, NP - 1)  # dead can be NP
     dest = jnp.take_along_axis(page_table, logical[:, None], axis=1)[:, 0]
-    dest = jnp.where(live, dest, 0)
-    off = positions % ps
-    vals = values * live[:, None, None].astype(values.dtype)
-    return pages.at[dest, off].set(vals)
+    return jnp.where(live, dest, 0), positions % page_size, live
 
 
-def pool_scatter_span(pages: jnp.ndarray, page_table: jnp.ndarray,
-                      start: jnp.ndarray, values: jnp.ndarray,
-                      n_valid: jnp.ndarray) -> jnp.ndarray:
-    """Write a span of C new tokens per slot (chunked prefill).
+def span_dest(page_table: jnp.ndarray, start: jnp.ndarray,
+              n_valid: jnp.ndarray, span: int, page_size: int):
+    """Where a span of ``span`` new tokens per slot is written (chunked
+    prefill): ``(page, row, keep)``, each [B, span], for ``pool_scatter``.
 
-    pages [P, ps, KV, dh]; page_table [B, NP]; start [B] (first logical
-    position of the span); values [B, C, KV, dh]; n_valid [B] (tokens of the
-    span that are real — the rest are padding and are routed, zeroed, to the
+    start [B] (first logical position of the span); n_valid [B] (tokens of
+    the span that are real — the rest are padding and go, zeroed, to the
     trash page 0).
     """
-    ps = pages.shape[1]
-    B, C = values.shape[:2]
-    tok_pos = start[:, None] + jnp.arange(C)[None, :]          # [B, C]
-    valid = jnp.arange(C)[None, :] < n_valid[:, None]          # [B, C]
-    logical = jnp.clip(tok_pos // ps, 0, page_table.shape[1] - 1)
+    tok_pos = start[:, None] + jnp.arange(span)[None, :]       # [B, C]
+    valid = jnp.arange(span)[None, :] < n_valid[:, None]       # [B, C]
+    logical = jnp.clip(tok_pos // page_size, 0, page_table.shape[1] - 1)
     dest = jnp.take_along_axis(page_table, logical, axis=1)    # [B, C]
-    dest = jnp.where(valid, dest, 0)
-    off = tok_pos % ps
-    vals = values * valid[:, :, None, None].astype(values.dtype)
-    return pages.at[dest, off].set(vals)
+    return jnp.where(valid, dest, 0), tok_pos % page_size, valid
+
+
+def pool_scatter(pages: jnp.ndarray, layer, dest,
+                 values: jnp.ndarray) -> jnp.ndarray:
+    """Write new tokens into one layer of the pool, in place.
+
+    pages [L, P, ps, KV, dh]; layer: int32 scalar (may be traced); dest
+    ``(page, row, keep)`` from ``token_dest`` ([B]) or ``span_dest``
+    ([B, C]); values [B, KV, dh] or [B, C, KV, dh]. Tokens not kept write
+    zeros.
+    """
+    page, row, keep = dest
+    vals = values * keep[..., None, None].astype(values.dtype)
+    return pages.at[layer, page, row].set(vals)
 
 
 def _kernel(k_ref, min_ref, max_ref):

@@ -523,10 +523,14 @@ def decode_step_paged(params, cfg: ArchConfig, token, pool, live, *,
     RoPE position, its own cache-write offset, and its own attention mask —
     no slot pays for the longest sequence's watermark, and the sparse-method
     fallback cond sees the true max over live slots instead of a shared
-    scalar. Returns (logits [B, V], pool') with pages updated in place and
-    live lengths advanced by one.
+    scalar. Returns (logits [B, V], pool') with live lengths advanced by one.
+
+    The layer loop carries the whole stacked pool: each layer writes its
+    token into the carried ``[L, P, ps, KV, dh]`` pages and gathers its view
+    from them by layer index, so no layer's pages are sliced out or
+    restacked, and with the pool donated the step updates it in place.
     """
-    from repro.kernels.page_pool import pool_gather, pool_scatter_token
+    from repro.kernels.page_pool import pool_gather, pool_scatter, token_dest
 
     B = token.shape[0]
     lengths = pool["lengths"]
@@ -538,30 +542,32 @@ def decode_step_paged(params, cfg: ArchConfig, token, pool, live, *,
     if cfg.rope_style == "mrope" and positions3 is None:
         positions3 = jnp.broadcast_to(lengths[None, :, None], (3, B, 1))
     cos, sin = _rope_tables(cfg, positions, positions3)
+    dest = token_dest(table, lengths, live, pool["k_pages"].shape[2])
 
-    def layer_fn(x, lp_kv):
-        lp, kp, vp, sp = lp_kv
+    def layer_fn(carry, xs):
+        x, kp, vp = carry
+        lp, sp, layer = xs
         q, k, v = _qkv(lp, x, cos, sin, cfg, tp)
         with jax.named_scope("kv_write"):
-            kp = pool_scatter_token(kp, table, lengths, k[:, 0], live)
-            vp = pool_scatter_token(vp, table, lengths, v[:, 0], live)
+            kp = pool_scatter(kp, layer, dest, k[:, 0])
+            vp = pool_scatter(vp, layer, dest, v[:, 0])
         with jax.named_scope("retrieve"):
-            kc = pool_gather(kp, table)
-            vc = pool_gather(vp, table)
+            kc = pool_gather(kp, layer, table)
+            vc = pool_gather(vp, layer, table)
         if sparse_fn is not None:
             res = sparse_fn(q, kc, vc, lengths + 1, sp, k_new=k)
             attn = res[0] if isinstance(res, tuple) else res
         else:
             with jax.named_scope("apply"):
                 attn = A.attention_decode(q, kc, vc, lengths + 1, cfg, tp=tp)
-        return _out_mlp(lp, x, attn, cfg, tp), (kp, vp)
+        return (_out_mlp(lp, x, attn, cfg, tp), kp, vp), None
 
     sp_stack = sparse_params
     if sp_stack is None:
         sp_stack = jnp.zeros((cfg.n_layers,), jnp.int32)   # dummy scan leaf
-    x, (k_new, v_new) = jax.lax.scan(
-        layer_fn, x, (params["layers"], pool["k_pages"], pool["v_pages"],
-                      sp_stack))
+    (x, k_new, v_new), _ = jax.lax.scan(
+        layer_fn, (x, pool["k_pages"], pool["v_pages"]),
+        (params["layers"], sp_stack, jnp.arange(cfg.n_layers)))
     pool = dict(pool, k_pages=k_new, v_pages=v_new,
                 lengths=lengths + live.astype(jnp.int32))
     return _logits(params, cfg, x), pool
@@ -606,7 +612,7 @@ def decode_step_paged_presel(params, cfg: ArchConfig, token, pool, live,
     from repro.core import placement
     from repro.core.methods.dsa import strip_dead_heads, repad_dead_heads
     from repro.kernels import ops
-    from repro.kernels.page_pool import pool_gather, pool_scatter_token
+    from repro.kernels.page_pool import pool_gather, pool_scatter, token_dest
 
     B = token.shape[0]
     ps = page_size
@@ -624,16 +630,18 @@ def decode_step_paged_presel(params, cfg: ArchConfig, token, pool, live,
     lb = lengths + 1                       # context incl. this step's token
     cur_page = lengths // ps               # page receiving this step's write
     use_sparse = placement.traced_use_sparse(lb, mem)
+    dest = token_dest(table, lengths, live, pool["k_pages"].shape[2])
 
-    def layer_fn(x, lp_kv):
-        lp, kp, vp, sel = lp_kv
+    def layer_fn(carry, xs):
+        x, kp, vp = carry
+        lp, sel, layer = xs
         q, k, v = _qkv(lp, x, cos, sin, cfg, tp)
         with jax.named_scope("kv_write"):
-            kp = pool_scatter_token(kp, table, lengths, k[:, 0], live)
-            vp = pool_scatter_token(vp, table, lengths, v[:, 0], live)
+            kp = pool_scatter(kp, layer, dest, k[:, 0])
+            vp = pool_scatter(vp, layer, dest, v[:, 0])
         with jax.named_scope("retrieve"):
-            kc = pool_gather(kp, table)
-            vc = pool_gather(vp, table)
+            kc = pool_gather(kp, layer, table)
+            vc = pool_gather(vp, layer, table)
 
         def sparse(_):
             with jax.named_scope("retrieve"):
@@ -662,12 +670,11 @@ def decode_step_paged_presel(params, cfg: ArchConfig, token, pool, live,
                 return repad_dead_heads(out, q, cfg)
 
         attn = jax.lax.cond(use_sparse, sparse, dense, None)
-        return (_out_mlp(lp, x, attn, cfg, tp),
-                (kp, vp, q[:, 0], k[:, 0]))
+        return (_out_mlp(lp, x, attn, cfg, tp), kp, vp), (q[:, 0], k[:, 0])
 
-    x, (k_new, v_new, q_layers, k_layers) = jax.lax.scan(
-        layer_fn, x, (params["layers"], pool["k_pages"], pool["v_pages"],
-                      pidx))
+    (x, k_new, v_new), (q_layers, k_layers) = jax.lax.scan(
+        layer_fn, (x, pool["k_pages"], pool["v_pages"]),
+        (params["layers"], pidx, jnp.arange(cfg.n_layers)))
     pool = dict(pool, k_pages=k_new, v_pages=v_new,
                 lengths=lengths + live.astype(jnp.int32))
     return _logits(params, cfg, x), pool, q_layers, k_layers
@@ -696,7 +703,7 @@ def extend_paged(params, cfg: ArchConfig, tokens, pool, n_valid, *,
     retrieved memory embeddings into a slot's context through the exact
     same chunked path its documents would take.
     """
-    from repro.kernels.page_pool import pool_gather, pool_scatter_span
+    from repro.kernels.page_pool import pool_gather, pool_scatter, span_dest
 
     B, C = tokens.shape
     lengths = pool["lengths"]
@@ -711,24 +718,26 @@ def extend_paged(params, cfg: ArchConfig, tokens, pool, n_valid, *,
     if cfg.rope_style == "mrope":
         positions3 = jnp.broadcast_to(positions[None], (3, B, C))
     cos, sin = _rope_tables(cfg, positions, positions3)
+    dest = span_dest(table, lengths, n_valid, C, pool["k_pages"].shape[2])
 
-    def layer_fn(x, lp_kv):
-        lp, kp, vp = lp_kv
+    def layer_fn(carry, xs):
+        x, kp, vp = carry
+        lp, layer = xs
         q, k, v = _qkv(lp, x, cos, sin, cfg, tp)
         with jax.named_scope("kv_write"):
-            kp = pool_scatter_span(kp, table, lengths, k, n_valid)
-            vp = pool_scatter_span(vp, table, lengths, v, n_valid)
+            kp = pool_scatter(kp, layer, dest, k)
+            vp = pool_scatter(vp, layer, dest, v)
         with jax.named_scope("retrieve"):
-            kc = pool_gather(kp, table)
-            vc = pool_gather(vp, table)
+            kc = pool_gather(kp, layer, table)
+            vc = pool_gather(vp, layer, table)
         with jax.named_scope("apply"):
             attn = A.attention_decode_chunk(q, kc, vc, lengths, cfg, tp=tp)
         x = _out_mlp(lp, x, attn, cfg, tp)
-        return x, ((kp, vp, k, q) if collect_kq else (kp, vp))
+        return (x, kp, vp), ((k, q) if collect_kq else None)
 
-    x, ys = jax.lax.scan(
-        layer_fn, x, (params["layers"], pool["k_pages"], pool["v_pages"]))
-    k_new, v_new = ys[0], ys[1]
+    (x, k_new, v_new), kq = jax.lax.scan(
+        layer_fn, (x, pool["k_pages"], pool["v_pages"]),
+        (params["layers"], jnp.arange(cfg.n_layers)))
     last = jnp.clip(n_valid - 1, 0, C - 1)
     with jax.named_scope("dense"):
         x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
@@ -737,7 +746,7 @@ def extend_paged(params, cfg: ArchConfig, tokens, pool, n_valid, *,
     pool = dict(pool, k_pages=k_new, v_pages=v_new, lengths=lengths + n_valid)
     if not collect_kq:
         return logits, pool
-    k_span, q_span = ys[2], ys[3]
+    k_span, q_span = kq
     q_last = jnp.take_along_axis(
         q_span, last[None, :, None, None, None], axis=2)[:, :, 0]
     return logits, pool, k_span, q_last
