@@ -1,8 +1,9 @@
 """Finding the pieces that belong to one name: a per-layer metric's reader
-(bench/metrics/<metric>.py, ``read(ctx)``), a kernel's or a step's cost
-function (bench/kernels/<name>.py, ``cost(config, ...)``), a
-configuration's plain reference (bench/reference/<name>.py), and the
-context a reader is handed."""
+(bench/metrics/<metric>.py, ``read(ctx)``), a kernel's cost function
+(bench/kernels/<name>.py, ``cost(config, ...)``), a
+configuration's plain reference (bench/reference/<name>.py) and its
+architecture (bench/archs/<arch>.py), and the context a reader is
+handed."""
 from __future__ import annotations
 
 import dataclasses
@@ -34,7 +35,8 @@ class Context:
     """What a per-layer reader may read."""
     cell: Any                         # spec.Cell
     stats: Dict[str, Any]             # drive.window_stats of the run
-    trace: Optional[Any]              # trace.Trace of the window, or None
+    trace: Optional[Any]              # scopes.ScopedTrace of the window,
+                                      # or None
     peak: Dict[str, Any]              # bench/peaks.json entry of the device
     contexts: List[float]             # mean live context of each decoding
                                       # session over the window

@@ -19,7 +19,7 @@ from typing import Callable, Dict, Optional
 import jax
 import numpy as np
 
-from benchlib import correct, drive, readers, spec, trace, weights
+from benchlib import correct, drive, readers, scopes, spec, trace, weights
 from benchlib.spec import BENCH, ROOT
 
 pc = time.perf_counter
@@ -74,7 +74,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     clock = CompileClock()
     traffic, config = cell.traffic, cell.config
-    cfg = spec.program_config(config, ArchConfig, MemoryConfig)
+    cfg = spec.arch(config).program_config(config, ArchConfig, MemoryConfig)
     sc = spec.serve_config(config, traffic, ServeConfig, OffloadConfig)
 
     params, indexer = weights.generate(config, seed)
@@ -143,12 +143,19 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
         tr = None
         xp = trace.find_xplane(str(trace_dir))
         if xp is not None:
-            raw = trace.extract(xp, config["hidden_size"])
+            raw = scopes.extract(xp, config["hidden_size"])
             if keep_trace:
                 trace.save(raw, keep_trace)
                 shutil.copy(xp, keep_trace + ".xplane.pb")
-            tr = trace.Trace.from_dict(raw)
+            tr = scopes.ScopedTrace.from_dict(raw)
             shutil.rmtree(trace_dir, ignore_errors=True)
+            per = 1e3 / max(steps, 1)
+            log("device ms per decode step by scope: " + ", ".join(
+                f"{k} {v * per:.3f}" for k, v in
+                tr.scope_s(exclude=scopes.DECODE_ONLY).items()))
+            log("idle ms per decode step by host span: " + ", ".join(
+                f"{k} {v * per:.3f}" for k, v in
+                sorted(tr.idle_by_span().items(), key=lambda kv: -kv[1])))
         contexts = []
         for r in sessions:
             a = len(r.prompt) + sum(1 for s in r.stamps if s < t_open)

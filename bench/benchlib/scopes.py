@@ -2,13 +2,15 @@
 trace: what the per-layer metrics of the memory-pipeline stages and of the
 engine's host turn read.
 
-A superset of bench/benchlib/trace.py, which it leaves as it is:
+Built on bench/benchlib/trace.py's ``Trace``:
 
-``extract``      ``trace.extract``'s dict with two additions: each op also
-                 carries its scope, the innermost of ``SCOPES`` in its
-                 ``op_name`` metadata (the program's ``jax.named_scope``),
-                 "" where none; and the engine's host spans (``engine.*``)
-                 are kept beside the benchmark's (``bench.*``).
+``extract``      reads the ``.xplane.pb`` the JAX profiler wrote into the
+                 dict ``ScopedTrace.from_dict`` reads: the programs, the ops
+                 (device, start, duration, short name, token rows, and the
+                 scope: the innermost of ``SCOPES`` in the op's ``op_name``
+                 metadata, the program's ``jax.named_scope``, "" where none)
+                 and the host spans of the benchmark (``bench.*``) and of
+                 the engine (``engine.*``).
 ``ScopedTrace``  a ``trace.Trace`` that also sums device time per scope,
                  splits idle time by the innermost host span open at each
                  instant, names each long idle gap by the innermost span
@@ -28,7 +30,6 @@ The readings (ms per decode step, over the ops outside prefill programs,
 as ``decode_step_ms`` reads them):
 
 ``stage_ms``      one memory-pipeline stage's device time;
-``memory_share``  the four stages' share of the decode device time;
 ``host_turn_ms``  device idle time whose innermost host span is the
                   engine's.
 """
@@ -76,8 +77,9 @@ def instruction_name(hlo: str) -> str:
 
 
 def extract(xplane_path: str, hidden: int) -> Dict:
-    """``trace.extract``'s dict for one xplane file, its ops with a sixth
-    field (the scope) and its spans with the engine's."""
+    """Device programs, scoped ops and host spans of one xplane file, as a
+    dict that ``ScopedTrace.from_dict`` reads and that can be stored as
+    JSON."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(xplane_path)
@@ -384,9 +386,9 @@ def stage_ms(tr: Optional[ScopedTrace], decode_steps: int, stage: str,
              cell: str = "") -> Optional[float]:
     """Device ms per decode step of the ops under one scope, over the ops
     ``decode_step_ms`` reads (every op outside prefill programs) and the
-    engine's decode steps. None for a program that names no scope; an
-    error where the program does but the stage took no time in a window
-    that should have decoded."""
+    engine's decode steps. None without a trace or for a program that
+    names no scope; an error where the program does but the stage took no
+    time in a window that should have decoded."""
     if tr is None or not tr.scoped:
         return None
     sec = tr.scope_s(exclude=DECODE_ONLY)[stage]
@@ -398,24 +400,11 @@ def stage_ms(tr: Optional[ScopedTrace], decode_steps: int, stage: str,
     return 1e3 * sec / decode_steps
 
 
-def memory_share(tr: Optional[ScopedTrace]) -> Optional[float]:
-    """100 x the four stages' device time over the device time outside
-    prefill programs (``decode_step_ms``'s numerator). None for a program
-    that names no scope."""
-    if tr is None or not tr.scoped:
-        return None
-    busy = tr.busy_s(exclude=DECODE_ONLY)
-    if busy <= 0:
-        return None
-    scopes = tr.scope_s(exclude=DECODE_ONLY)
-    return 100.0 * sum(scopes[s] for s in STAGES) / busy
-
-
 def host_turn_ms(tr: Optional[ScopedTrace], decode_steps: int,
                  cell: str = "") -> Optional[float]:
     """Device idle ms per decode step whose innermost host span is one of
-    the engine's (``engine.poll`` and the phases inside it). None for a
-    program without those spans."""
+    the engine's (``engine.poll`` and the phases inside it). None without
+    a trace or for a program without those spans."""
     if tr is None:
         return None
     idle = [v for k, v in tr.idle_by_span().items()

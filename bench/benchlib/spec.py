@@ -4,11 +4,16 @@ its traffic file, all found by name.
     bench/configs/<config>.json   sizes as published (keys of the model's own
                                   config.json), what was cut (``reduced``),
                                   what the program needs to run it
-                                  (``program``, ``memory``)
+                                  (``program``, ``memory``), and its
+                                  architecture (``arch``)
+    bench/archs/<arch>.py         what the harness knows of that
+                                  architecture's shape: the program's
+                                  config, the weights, the FLOPs of a step,
+                                  the CPU scale-down
     bench/traffic/<traffic>.json  parameters of one traffic mix
 
-Nothing here imports the program: ``program_config`` and ``serve_config``
-are handed the program's classes by the caller.
+Nothing here imports the program: ``serve_config`` and an architecture's
+``program_config`` are handed the program's classes by the caller.
 """
 from __future__ import annotations
 
@@ -17,20 +22,10 @@ import json
 import pathlib
 from typing import Any, Dict
 
+from benchlib import readers
+
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
-
-# published config.json key -> field of the program's ArchConfig
-ARCH_KEYS = {
-    "hidden_size": "d_model",
-    "intermediate_size": "d_ff",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "num_hidden_layers": "n_layers",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-}
 
 
 @dataclasses.dataclass
@@ -71,25 +66,10 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
                 bench=bench)
 
 
-def program_config(config: Dict[str, Any], ArchConfig, MemoryConfig):
-    """The program's ArchConfig for a configuration file: every size from
-    the file, the mechanisms the file's ``program`` block names."""
-    prog = config["program"]
-    mem = config["memory"]
-    kw = {field: config[key] for key, field in ARCH_KEYS.items()}
-    kw["head_dim"] = config.get("head_dim") or (
-        config["hidden_size"] // config["num_attention_heads"])
-    kw["rope_theta"] = float(kw["rope_theta"])
-    kw["norm_eps"] = float(kw["norm_eps"])
-    return ArchConfig(
-        name=config["name"], family=prog["family"],
-        qk_norm=bool(prog["qk_norm"]), qkv_bias=bool(prog["qkv_bias"]),
-        dtype=config.get("torch_dtype", "bfloat16"),
-        memory=MemoryConfig(method=prog["method"],
-                            index_heads=mem["index_heads"],
-                            index_dim=mem["index_dim"], top_k=mem["top_k"],
-                            min_context=mem["min_context"]),
-        **kw)
+def arch(config: Dict[str, Any]):
+    """The architecture module a configuration file names (``arch``):
+    bench/archs/<arch>.py."""
+    return readers.module("archs", config["arch"])
 
 
 def serve_config(config: Dict[str, Any], traffic: Dict[str, Any],

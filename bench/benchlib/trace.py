@@ -2,12 +2,12 @@
 
 Two steps, kept apart so the second can be checked on a recorded trace:
 
-``extract``  reads the ``.xplane.pb`` the JAX profiler wrote and keeps, as
-             plain lists: the program executions of each device (plane
-             ``/device:<KIND>:<n>``, line ``XLA Modules``), its operations
-             (line ``XLA Ops``, by short name), and the benchmark's own host
-             spans (``bench.*``). Each operation also keeps the largest
-             token count T of any [B, T, hidden] activation in its HLO text.
+``scopes.extract`` reads the ``.xplane.pb`` the JAX profiler wrote and
+             keeps, as plain lists: the program executions of each device
+             (plane ``/device:<KIND>:<n>``, line ``XLA Modules``), its
+             operations (line ``XLA Ops``, by short name), and the host
+             spans. Each operation also keeps the largest token count T of
+             any [B, T, hidden] activation in its HLO text (``token_rows``).
 ``Trace``    sums those: busy time as the union of operation intervals per
              device; per program kind the time and executions (a program
              whose activations carry one token a row is ``decode``, more
@@ -75,43 +75,6 @@ def token_rows(hlo: str, hidden: int) -> int:
     out = hlo.split(" = ", 1)[1] if " = " in hlo else ""
     m = re.search(rf"\[\d+,(\d+),{hidden}\]", out.split(" while(", 1)[0])
     return int(m.group(1)) if m else 0
-
-
-def extract(xplane_path: str, hidden: int) -> Dict:
-    """Device programs, ops and bench host spans of one xplane file, as a
-    dict that ``Trace.from_dict`` reads and that can be stored as JSON."""
-    from jax.profiler import ProfileData
-
-    pd = ProfileData.from_file(xplane_path)
-    ops, modules, spans, devices = [], [], [], []
-    for plane in pd.planes:
-        if plane.name.startswith("/device:"):
-            try:
-                dev = int(plane.name.rsplit(":", 1)[1])
-            except ValueError:
-                continue
-            for ln in plane.lines:
-                if ln.name == OPS_LINE:
-                    if dev not in devices:
-                        devices.append(dev)
-                    for e in ln.events:
-                        ops.append([dev, float(e.start_ns),
-                                    float(e.duration_ns), short_name(e.name),
-                                    token_rows(e.name, hidden)])
-                elif ln.name == MODULES_LINE:
-                    for e in ln.events:
-                        run = dict(e.stats).get("run_id", -1)
-                        modules.append([dev, float(e.start_ns),
-                                        float(e.duration_ns), e.name,
-                                        int(run)])
-        elif plane.name.startswith("/host:"):
-            for ln in plane.lines:
-                for e in ln.events:
-                    if e.name.startswith("bench."):
-                        spans.append([float(e.start_ns), float(e.duration_ns),
-                                      e.name])
-    return {"devices": sorted(devices), "modules": modules, "ops": ops,
-            "spans": spans}
 
 
 def find_xplane(trace_dir: str) -> Optional[str]:

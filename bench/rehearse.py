@@ -77,19 +77,19 @@ def rehearse(name: str, dev) -> None:
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from benchlib import spec, weights
+    from benchlib import spec
     from repro.configs.base import ArchConfig, MemoryConfig
     from repro.serving import OffloadConfig, ServeConfig
 
     cell = spec.load_cell(name)
-    cfg = spec.program_config(cell.config, ArchConfig, MemoryConfig)
+    arch = spec.arch(cell.config)
+    cfg = arch.program_config(cell.config, ArchConfig, MemoryConfig)
     sc = spec.serve_config(cell.config, cell.traffic, ServeConfig,
                            OffloadConfig)
     on = SingleDeviceSharding(dev)
     shaped = lambda t: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on), t)
-    params, indexer = shaped(jax.eval_shape(
-        weights._generate, jax.random.PRNGKey(0), weights.sizes(cell.config)))
+    params, indexer = shaped(arch.shapes(cell.config))
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=on)
     B, L = sc.n_slots, sc.max_len
     ps = sc.kv_page_size

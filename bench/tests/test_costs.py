@@ -34,8 +34,8 @@ def test_paged_decode_attention_both_configs():
 def test_decode_step_qwen3():
     # per layer: weights 975,175,680 + indexer 154,176,448
     # + attention 67,108,864; x 4 layers; + lm_head 2*5120*151936
-    flops = readers.module("kernels", "decode_step").cost(
-        config("qwen3-32b-l4"), 18000)
+    qwen3 = config("qwen3-32b-l4")
+    flops = spec.arch(qwen3).decode_flops(qwen3, 18000)
     assert flops == 4 * (975_175_680 + 154_176_448 + 67_108_864) \
         + 1_555_824_640
 

@@ -1,5 +1,6 @@
 """Cells at a size a CPU test can hold: the cell's own files with every
-width and length scaled down, so the whole run (weights, warm-up, window,
+width and length scaled down (the model by its architecture module's
+``tiny``, the traffic here), so the whole run (weights, warm-up, window,
 trace reduction, reference) can be driven without a chip."""
 from __future__ import annotations
 
@@ -12,11 +13,6 @@ sys.path.insert(0, str(BENCH.parent / "src"))
 
 from benchlib import spec  # noqa: E402
 
-TINY_MODEL = {"hidden_size": 256, "intermediate_size": 512,
-              "num_attention_heads": 4, "num_key_value_heads": 2,
-              "head_dim": 64, "num_hidden_layers": 2, "vocab_size": 1000}
-TINY_MEMORY = {"index_heads": 4, "index_dim": 32, "top_k": 64, "page": 16,
-               "min_context": 256}
 TINY_TRAFFIC = {
     "long-decode": {"engine": {"n_slots": 2, "max_len": 1024},
                     "sessions": {"count": 2, "max_new": 400, "warm_tokens": 4,
@@ -40,8 +36,7 @@ def tiny_cell(name: str) -> spec.Cell:
         config=spec.load_json(spec.BENCH / "configs" / f"{config}.json"),
         traffic=spec.load_json(spec.BENCH / "traffic" / f"{mix}.json"),
         bench=spec.load_json(spec.ROOT / "BENCHMARK.json"))
-    cell.config.update(TINY_MODEL)
-    cell.config["memory"] = dict(cell.config["memory"], **TINY_MEMORY)
+    cell.config = spec.arch(cell.config).tiny(cell.config)
     mix = cell.traffic["name"]
     for k, v in TINY_TRAFFIC[mix].items():
         cell.traffic[k] = v
