@@ -82,6 +82,35 @@ class ArchConfig:
     n_experts: int = 0
     experts_per_token: int = 0
     capacity_factor: float = 1.25
+    # MoE as DeepSeek-V3 routes it (models/mla.py, moe.noaux_moe_apply):
+    # sigmoid scores, a correction bias that only picks,
+    # ``topk_expert_groups`` of ``n_expert_groups`` groups, weights
+    # normalised and scaled by ``routed_scaling``; dropless, over
+    # ``n_experts`` (the router's width) of which this chip holds
+    # ``n_held_experts`` from ``first_held_expert`` on (0 -> all), each
+    # ``moe_d_ff`` wide, plus ``n_shared_experts`` always-on experts; the
+    # first ``first_k_dense`` layers are dense
+    n_expert_groups: int = 1
+    topk_expert_groups: int = 1
+    routed_scaling: float = 1.0
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    n_held_experts: int = 0
+    first_held_expert: int = 0
+    first_k_dense: int = 0
+    # multi-head latent attention (DeepSeek-V2/V3); kv_lora_rank 0 -> none
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling; rope_factor 1 -> plain rope. The rope's cos and sin
+    # stay unscaled (the published mscale equals mscale_all_dim)
+    rope_factor: float = 1.0
+    rope_original_max_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 1.0
     # SSM / hybrid (Mamba2)
     ssm_state: int = 0
     ssm_conv: int = 4
@@ -99,6 +128,16 @@ class ArchConfig:
     memory: MemoryConfig = dataclasses.field(default_factory=MemoryConfig)
 
     # ------------------------------------------------------------------
+    @property
+    def is_mla(self) -> bool:
+        """Latent attention (models/mla.py) with the published DSA indexer
+        in place of GQA."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> int:
+        return self.n_held_experts or self.n_experts
+
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))

@@ -35,7 +35,10 @@ OFFLOAD_STAGES = ("prepare", "relevancy", "retrieve")
 
 
 def dsa_init(key, cfg: ArchConfig, mem: MemoryConfig, stacked: bool = True):
-    """Per-layer lightning-indexer params, stacked [L, ...] for the scan."""
+    """Per-layer lightning-indexer params, stacked [L, ...] for the scan
+    (the published indexer for a latent-attention model)."""
+    if cfg.is_mla:
+        return published_init(key, cfg, mem)
     hd = cfg.hd
     hp_in = cfg.n_heads * hd  # from query heads (pre-o-proj activations)
     kv_in = cfg.n_kv_heads * hd
@@ -262,3 +265,75 @@ def build_pipeline(cfg: ArchConfig, mem: MemoryConfig, sp: Params, *,
         fused={"relevancy": ("relevancy", "retrieve")} if fused else {},
     )
     return pipe
+
+
+# ---------------------------------------------------------------------------
+# The published lightning indexer (DeepSeek-V3.2-Exp), for models/mla.py:
+# its own cache of one index key per token, index queries from the query
+# latent, and token-level top-k.
+# ---------------------------------------------------------------------------
+
+
+def published_init(key, cfg: ArchConfig, mem: MemoryConfig):
+    """Per-layer indexer weights, stacked [L, ...]: ``wq_b`` (query latent
+    -> index queries), ``wk`` (hidden -> index key), ``k_norm`` (a
+    LayerNorm's gain and bias), ``w_proj`` (hidden -> head weights)."""
+    d, Hi, di = cfg.d_model, mem.index_heads, mem.index_dim
+
+    def one(k):
+        k1, k2, k3 = jax.random.split(k, 3)
+        return {
+            "wq_b": L.dense_init(k1, cfg.q_lora_rank, Hi * di, jnp.bfloat16),
+            "wk": L.dense_init(k2, d, di, jnp.bfloat16),
+            "k_norm": {"w": jnp.ones((di,), jnp.float32),
+                       "b": jnp.zeros((di,), jnp.float32)},
+            "w_proj": L.dense_init(k3, d, Hi, jnp.bfloat16),
+        }
+
+    return jax.lax.map(one, jax.random.split(key, cfg.n_layers))
+
+
+def _layer_norm(p, x, eps):
+    xf = x.astype(jnp.float32)
+    mu = xf.mean(-1, keepdims=True)
+    var = ((xf - mu) ** 2).mean(-1, keepdims=True)
+    return (xf - mu) * jax.lax.rsqrt(var + eps) * p["w"] + p["b"]
+
+
+def index_key(sp: Params, h, cos, sin, eps: float):
+    """Prepare: the index key of each new token, h [B, T, d] ->
+    [B, T, di] = LayerNorm(h W_k), rope on its first 2 * cos.shape[-1]
+    dims."""
+    k = _layer_norm(sp["k_norm"], h @ sp["wk"], eps)
+    return L.apply_rope(k[:, :, None], cos, sin)[:, :, 0].astype(h.dtype)
+
+
+def index_query(sp: Params, c_q, h, cos, sin):
+    """Relevancy, query side: c_q [B, q_lora] (the query latent), h [B, d]
+    -> (index queries [B, Hi, di] roped like the keys, head weights [B, Hi]
+    = h W_w * Hi^-1/2 * di^-1/2)."""
+    B = c_q.shape[0]
+    Hi = sp["w_proj"].shape[-1]
+    q = (c_q @ sp["wq_b"]).reshape(B, 1, Hi, -1)
+    q = L.apply_rope(q, cos, sin)[:, 0]
+    w = (h @ sp["w_proj"]).astype(jnp.float32)
+    return q, w * (Hi ** -0.5) * (q.shape[-1] ** -0.5)
+
+
+def token_topk(q, w, keys, context, top_k: int):
+    """Relevancy and selection over every cached index key: score
+    s_t = sum_h w_h relu(q_h . k_t) for t < context, the rest never chosen;
+    q [B, Hi, di], w [B, Hi], keys [B, S, di], context [B].
+    -> (token ids [B, n], chosen [B]): the n = min(top_k, S) best ids, best
+    first, of which the first ``chosen`` = min(top_k, context) are real."""
+    S = keys.shape[1]
+    exact = jax.lax.Precision.HIGHEST      # float32 passes, as the oracle
+    dots = jnp.einsum("bhd,bsd->bhs", q.astype(jnp.float32),
+                      keys.astype(jnp.float32), precision=exact)
+    scores = jnp.einsum("bh,bhs->bs", w, jax.nn.relu(dots), precision=exact)
+    scores = jnp.where(jnp.arange(S)[None] < context[:, None], scores,
+                       -jnp.inf)
+    n = min(top_k, S)
+    _, ids = jax.lax.top_k(scores, n)
+    return ids.astype(jnp.int32), jnp.minimum(context, n).astype(jnp.int32)
+

@@ -23,8 +23,9 @@ import jax
 
 STAGES = ("prepare", "relevancy", "retrieve", "apply")
 # The ``jax.named_scope`` names a decode step's ops carry: the four stages,
-# the step's K/V written into the cache, and the dense model around them.
-SCOPES = STAGES + ("kv_write", "dense")
+# the step's K/V written into the cache, the dense model around them, and
+# inside ``dense`` a mixture-of-experts FFN's router and experts.
+SCOPES = STAGES + ("kv_write", "dense", "moe")
 
 
 @dataclasses.dataclass

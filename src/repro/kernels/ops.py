@@ -19,6 +19,7 @@ from repro.kernels import sparse_decode_attention as _sda
 from repro.kernels import flash_attention as _fa
 from repro.kernels import page_pool as _pp
 from repro.kernels import bm25_topk as _bm
+from repro.kernels import mla_sparse_decode as _mla
 
 _STATE = {"pallas": True}
 
@@ -78,6 +79,23 @@ def paged_decode_attention(q, k_cache, v_cache, page_ids, length, *,
 
 
 lse_merge = _sda.lse_merge
+
+
+def mla_sparse_decode_attention(q, rows, n_valid, *, dv: int, scale: float,
+                                block: int = 512):
+    """Absorbed MLA attention over selected latent rows -> [B, H, dv]
+    float32. Pads the row axis to a whole number of blocks (pad rows lie
+    past ``n_valid``)."""
+    if not _STATE["pallas"]:
+        return ref.mla_sparse_decode_attention(q, rows, n_valid, dv, scale)
+    N = rows.shape[1]
+    blk = _pow2_block(max(N, 2), block)
+    pad = (-N) % blk
+    if pad:
+        rows = jnp.pad(rows, ((0, 0), (0, pad), (0, 0)))
+    return _mla.mla_sparse_decode_attention(
+        q, rows, n_valid, dv=dv, scale=scale, block=blk,
+        interpret=_interp())
 
 
 def flash_attention(q, k, v, *, window: int = 0, bq: int = 512, bk: int = 512):

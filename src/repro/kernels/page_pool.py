@@ -51,6 +51,18 @@ def pool_gather(pages: jnp.ndarray, layer, page_table: jnp.ndarray
     return view.reshape(B, NP * ps, KV, dh)
 
 
+def pool_gather_rows(pages: jnp.ndarray, layer, page_table: jnp.ndarray,
+                     tokens: jnp.ndarray) -> jnp.ndarray:
+    """Chosen tokens of one layer of the pool, without the per-slot view.
+
+    pages [L, P, ps, KV, dh]; page_table [B, NP]; tokens [B, N] int32
+    logical positions -> [B, N, KV, dh]. Reads only the chosen rows.
+    """
+    ps = pages.shape[2]
+    page = jnp.take_along_axis(page_table, tokens // ps, axis=1)   # [B, N]
+    return pages[layer, page, tokens % ps]
+
+
 def token_dest(page_table: jnp.ndarray, positions: jnp.ndarray,
                live: jnp.ndarray, page_size: int):
     """Where one new token per slot is written: ``(page, row, keep)``, each

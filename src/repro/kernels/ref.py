@@ -155,3 +155,19 @@ def bm25_scores(tf: jnp.ndarray, doc_len: jnp.ndarray, idf: jnp.ndarray,
 def bm25_topk(tf, doc_len, idf, k: int, **kw):
     scores = bm25_scores(tf, doc_len, idf, **kw)
     return jax.lax.top_k(scores, min(k, scores.shape[-1]))  # match ops path
+
+
+# ---------------------------------------------------------------------------
+# Sparse MLA decode attention over selected latent rows
+# ---------------------------------------------------------------------------
+
+
+def mla_sparse_decode_attention(q, rows, n_valid, dv: int, scale: float):
+    """q [B,H,W]; rows [B,N,W]; n_valid [B] -> [B,H,dv]: softmax over the
+    first n_valid rows of (q . row) * scale, values the rows' first dv."""
+    sc = jnp.einsum("bhw,bnw->bhn", q.astype(jnp.float32),
+                    rows.astype(jnp.float32)) * scale
+    ok = jnp.arange(rows.shape[1])[None] < n_valid[:, None]
+    sc = jnp.where(ok[:, None], sc, NEG_INF)
+    p = jax.nn.softmax(sc, axis=-1)
+    return jnp.einsum("bhn,bnd->bhd", p, rows[..., :dv].astype(jnp.float32))

@@ -22,6 +22,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.models import attention as A
 from repro.models import layers as L
+from repro.models import mla
 from repro.models import moe as M
 from repro.models import ssm as S
 from repro.models import xlstm as X
@@ -86,6 +87,8 @@ def init_params(cfg: ArchConfig, key, tp: int = 16) -> Params:
     """Random params from ``key``. Each part is drawn by its own jitted
     call, so at published widths the float32 draw behind a bf16 weight is
     never held for more than one matrix at a time."""
+    if cfg.is_mla:
+        return mla.init_params(cfg, key)
     ke, kl, kf, kh = jax.random.split(key, 4)
     params: Params = {
         "embed": _embed_init(ke, cfg),
@@ -502,6 +505,9 @@ def make_page_pool(cfg: ArchConfig, n_slots: int, max_len: int, *,
     page-table entry points at it, dead-slot writes are routed (zeroed) to
     it, and it must stay zero so pooled decode equals per-request decode.
     """
+    if cfg.is_mla:
+        return mla.make_page_pool(cfg, n_slots, max_len, page_size=page_size,
+                                  total_pages=total_pages)
     dt = dtype or L.dtype_of(cfg)
     kv, hd = cfg.n_kv_heads, cfg.hd
     assert max_len % page_size == 0, (max_len, page_size)
@@ -529,7 +535,13 @@ def decode_step_paged(params, cfg: ArchConfig, token, pool, live, *,
     token into the carried ``[L, P, ps, KV, dh]`` pages and gathers its view
     from them by layer index, so no layer's pages are sliced out or
     restacked, and with the pool donated the step updates it in place.
+
+    A latent-attention config runs ``mla.decode_step_paged``, whose own
+    indexer (``sparse_params``) replaces ``sparse_fn``.
     """
+    if cfg.is_mla:
+        return mla.decode_step_paged(params, cfg, token, pool, live,
+                                     sparse_params=sparse_params)
     from repro.kernels.page_pool import pool_gather, pool_scatter, token_dest
 
     B = token.shape[0]
@@ -682,7 +694,7 @@ def decode_step_paged_presel(params, cfg: ArchConfig, token, pool, live,
 
 def extend_paged(params, cfg: ArchConfig, tokens, pool, n_valid, *,
                  tp: int = 16, collect_kq: bool = False, x_embeds=None,
-                 emb_rows=None):
+                 emb_rows=None, sparse_params=None):
     """Chunked prefill: append a span of C tokens per slot to the paged pool.
 
     tokens [B, C] int32 (rows padded past ``n_valid[b]``); pool from
@@ -702,7 +714,13 @@ def extend_paged(params, cfg: ArchConfig, tokens, pool, n_valid, *,
     context instead of token ids: the MaC retrieval service splices
     retrieved memory embeddings into a slot's context through the exact
     same chunked path its documents would take.
+
+    A latent-attention config runs ``mla.extend_paged``, which also writes
+    the span's index keys with the indexer ``sparse_params``.
     """
+    if cfg.is_mla:
+        return mla.extend_paged(params, cfg, tokens, pool, n_valid,
+                                sparse_params=sparse_params)
     from repro.kernels.page_pool import pool_gather, pool_scatter, span_dest
 
     B, C = tokens.shape

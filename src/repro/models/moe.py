@@ -116,3 +116,81 @@ def moe_apply(p: Params, x: jnp.ndarray, cfg: ArchConfig,
     aux_total, ys = jax.lax.scan(body, jnp.zeros((), jnp.float32), flat)
     y = ys.reshape(n_groups * g, d)[:tokens].reshape(B, S, d)
     return y, aux_total / n_groups
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V3 routing (``noaux_tc``): dropless, over the experts this chip
+# holds, plus the shared expert.
+# ---------------------------------------------------------------------------
+
+
+def noaux_moe_init(key, cfg: ArchConfig) -> Params:
+    """Router over all ``n_experts`` (float32 weight and correction bias),
+    the ``held_experts`` this chip holds, and the shared expert."""
+    d, ff, Eh = cfg.d_model, cfg.moe_d_ff, cfg.held_experts
+    dt = L.dtype_of(cfg)
+    ks = jax.random.split(key, 5)
+    expert = lambda k, a, b, s: (jax.random.normal(k, (Eh, a, b), jnp.float32)
+                                 * s).astype(dt)
+    return {
+        "gate": L.dense_init(ks[0], d, cfg.n_experts, jnp.float32),
+        "bias": jnp.zeros((cfg.n_experts,), jnp.float32),
+        "w1": expert(ks[1], d, ff, 1 / np.sqrt(d)),
+        "w3": expert(ks[2], d, ff, 1 / np.sqrt(d)),
+        "w2": expert(ks[3], ff, d, 1 / np.sqrt(2 * cfg.n_layers * ff)),
+        "shared": L.mlp_init(ks[4], cfg, d_ff=ff * cfg.n_shared_experts),
+    }
+
+
+def noaux_route(p: Params, x: jnp.ndarray, cfg: ArchConfig):
+    """x [T, d] -> (weights [T, k], expert ids [T, k]) over all n_experts.
+
+    Scores are sigmoids of a full float32 product, as the published gate
+    computes them; the correction bias is added only to choose: a
+    group's score is the sum of its two best biased scores, the
+    ``topk_expert_groups`` best groups stay, and the k best biased experts
+    inside them are chosen. The weights are the un-biased scores of the
+    chosen experts, normalised to sum 1 and scaled by ``routed_scaling``."""
+    T = x.shape[0]
+    E, G = cfg.n_experts, cfg.n_expert_groups
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), p["gate"],   # [T, E]
+                               precision=jax.lax.Precision.HIGHEST))
+    b = s + p["bias"]
+    group = jax.lax.top_k(b.reshape(T, G, E // G), 2)[0].sum(-1)   # [T, G]
+    _, keep = jax.lax.top_k(group, cfg.topk_expert_groups)
+    kept = jax.nn.one_hot(keep, G, dtype=jnp.float32).sum(1) > 0   # [T, G]
+    b = jnp.where(jnp.repeat(kept, E // G, axis=1), b, -jnp.inf)
+    _, idx = jax.lax.top_k(b, cfg.experts_per_token)
+    w = jnp.take_along_axis(s, idx, axis=1)
+    w = w / w.sum(-1, keepdims=True)
+    return w * cfg.routed_scaling, idx
+
+
+def held_expert_weights(p: Params, x: jnp.ndarray, cfg: ArchConfig):
+    """x [T, d] -> [T, held_experts]: each token's routing weight on each
+    expert this chip holds (0 where the router did not choose it)."""
+    w, idx = noaux_route(p, x, cfg)
+    held = cfg.first_held_expert + jnp.arange(cfg.held_experts)
+    return ((idx[:, :, None] == held[None, None]) * w[:, :, None]).sum(1)
+
+
+def held_experts_apply(p: Params, x: jnp.ndarray, cfg: ArchConfig):
+    """The held experts' part of the routed output, x [T, d] -> [T, d]: one
+    grouped product over every held expert for every token, each scaled by
+    its routing weight (0 for experts not chosen). A token's output depends
+    on its own routing alone, and each held expert is read once."""
+    w = held_expert_weights(p, x, cfg)                              # [T, Eh]
+    h = jax.nn.silu(jnp.einsum("td,edf->tef", x, p["w1"])) * jnp.einsum(
+        "td,edf->tef", x, p["w3"])                                  # [T,Eh,f]
+    h = h * w[:, :, None].astype(h.dtype)
+    return jnp.einsum("tef,efd->td", h, p["w2"])
+
+
+def noaux_moe_apply(p: Params, x: jnp.ndarray, cfg: ArchConfig):
+    """x [B, S, d] -> [B, S, d]: the held experts' part plus the shared
+    expert. Under expert parallelism each chip adds its own part; here the
+    chip's part goes on to the next layer as it is."""
+    B, S, d = x.shape
+    flat = x.reshape(B * S, d)
+    y = held_experts_apply(p, flat, cfg) + L.mlp(p["shared"], flat)
+    return y.reshape(B, S, d)

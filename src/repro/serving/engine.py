@@ -235,6 +235,13 @@ class Engine:
                 sc, max_len=((sc.max_len + gran - 1) // gran) * gran)
         self.sc = sc
         self._gran = gran
+        if cfg.is_mla:
+            # latent attention serves on the inline paged path only, with
+            # its own (published) indexer
+            assert sc.method == "dsa", sc.method
+            assert sc.paged and sc.offload == "off" and sc.fused_steps == 1 \
+                and sc.retrieval is None, \
+                "latent attention runs inline, stepped, without retrieval"
         self.sparse_params = None
         sparse_fn = None
         if sc.method != "none" and cfg.family != "ssm":
@@ -244,6 +251,7 @@ class Engine:
                 init_fn, static_argnums=(1, 2), static_argnames="stacked")(
                 key if key is not None else jax.random.PRNGKey(0),
                 cfg, self.mem, stacked=cfg.family != "hybrid")
+        if self.sparse_params is not None and not cfg.is_mla:
             kw = {"page": sc.page} if sc.method == "dsa" else {}
             raw = mk(cfg, self.mem, tp=sc.tp, **kw)
             mem = self.mem
@@ -442,8 +450,9 @@ class Engine:
         while self.queue and budget > 0:
             req = self.queue[0]
             plen = len(req)
-            chunked = self.sc.paged and bool(
-                req.override("chunked", plen > self.sc.chunk_threshold))
+            # a latent-attention model prefills every prompt in chunks
+            chunked = self.sc.paged and (self.cfg.is_mla or bool(
+                req.override("chunked", plen > self.sc.chunk_threshold)))
             if chunked:
                 if not self._admit_chunked(req.rid, req.tokens, req.max_new,
                                            retrieval=req.retrieval):
@@ -766,14 +775,17 @@ class Engine:
             cfg, sc = self.cfg, self.sc
             ckq = self.hetero is not None
 
-            def extend_paged(p, toks, kp, vp, table, lengths, nv, *emb):
-                # emb: (x_embeds, emb_rows) for the embedding-splice variant
-                xe, er = emb if embeds else (None, None)
+            def extend_paged(p, toks, kp, vp, table, lengths, nv, *extra):
+                # extra: (x_embeds, emb_rows) for the embedding-splice
+                # variant, or a latent-attention model's indexer, whose
+                # prefill also writes the index keys
+                xe, er = extra if embeds else (None, None)
                 return M.extend_paged(
                     p, cfg, toks,
                     {"k_pages": kp, "v_pages": vp, "page_table": table,
                      "lengths": lengths},
-                    nv, tp=sc.tp, collect_kq=ckq, x_embeds=xe, emb_rows=er)
+                    nv, tp=sc.tp, collect_kq=ckq, x_embeds=xe, emb_rows=er,
+                    sparse_params=extra[0] if cfg.is_mla else None)
 
             self._extend_fns[key] = jax.jit(extend_paged,
                                             donate_argnums=(2, 3))
@@ -812,6 +824,8 @@ class Engine:
         if x_embeds is not None:
             out = self._get_extend_fn(C, embeds=True)(
                 *args, jnp.asarray(x_embeds), jnp.asarray(emb_rows))
+        elif self.cfg.is_mla:
+            out = self._get_extend_fn(C)(*args, self.sparse_params)
         else:
             out = self._get_extend_fn(C)(*args)
         logits, pool = out[0], out[1]

@@ -98,10 +98,12 @@ def _ingest(eng):
 
 ENGINES = {"stepped": dict(), "fused": dict(fused_steps=4),
            "offload": dict(offload_cfg=OffloadConfig(mode="sync"))}
+# the scopes of a dense model's decode step (no ``moe``)
+DENSE = STAGES + ("kv_write", "dense")
 # program -> (its engine, its function and arguments, the scopes it runs)
 PROGRAMS = {
-    "decode_paged": ("stepped", _decode_paged, SCOPES),
-    "fused_decode": ("fused", _fused_decode, SCOPES),
+    "decode_paged": ("stepped", _decode_paged, DENSE),
+    "fused_decode": ("fused", _fused_decode, DENSE),
     # the offload split: the apply step on the KV pool's device, selection
     # and index upkeep on the offload side
     "decode_paged_presel": ("offload", _decode_paged_presel,
@@ -182,4 +184,4 @@ def test_kernels_sit_under_their_stage(name):
 def test_stage_names_are_the_pipeline_stages():
     assert SCOPES[:4] == STAGES == ("prepare", "relevancy", "retrieve",
                                     "apply")
-    assert set(SCOPES[4:]) == {"kv_write", "dense"}
+    assert set(SCOPES[4:]) == {"kv_write", "dense", "moe"}
