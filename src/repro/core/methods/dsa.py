@@ -24,6 +24,7 @@ import numpy as np
 from repro.configs.base import ArchConfig, MemoryConfig
 from repro.core.pipeline import MemoryPipeline
 from repro.kernels import ops
+from repro.kernels.page_pool import kv_view
 from repro.models import layers as L
 
 Params = Dict
@@ -71,9 +72,9 @@ def _index_q(sp: Params, q: jnp.ndarray):
 
 def _index_k(sp: Params, k_cache: jnp.ndarray):
     """Key half of the lightning indexer (prepare): k_cache [B, S, KV, hd]
-    -> k_idx [B, S, di]."""
+    (or ``PooledKV``, gathered here) -> k_idx [B, S, di]."""
     B, S = k_cache.shape[:2]
-    return k_cache.reshape(B, S, -1) @ sp["wk_idx"]
+    return kv_view(k_cache).reshape(B, S, -1) @ sp["wk_idx"]
 
 
 def strip_dead_heads(q: jnp.ndarray, cfg: ArchConfig):
@@ -94,7 +95,11 @@ def repad_dead_heads(out: jnp.ndarray, q_like: jnp.ndarray, cfg: ArchConfig):
 
 def make_sparse_fn(cfg: ArchConfig, mem: MemoryConfig, *, tp: int = 16,
                    page: int = 16, max_context: int = 0):
-    """Returns sparse_fn(q, kc, vc, length, sp) for model.decode_step."""
+    """Returns sparse_fn(q, kc, vc, length, sp) for model.decode_step.
+
+    kc / vc are views [B, S, KV, hd] or ``PooledKV`` handles: only the
+    index projection gathers the K view; the paged kernel reads the selected
+    pages straight from the pool."""
     from repro.models import attention as A
 
     n_pages_sel = max(mem.top_k // page, 1)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig, MemoryConfig
 from repro.core.pipeline import MemoryPipeline
 from repro.kernels import ops, ref as kref
+from repro.kernels.page_pool import kv_view
 
 Params = Dict
 
@@ -53,7 +54,7 @@ def make_sparse_fn(cfg: ArchConfig, mem: MemoryConfig, *, tp: int = 16):
         S = kc.shape[1]
         with jax.named_scope("prepare"):
             # page min/max pooling (Pallas kernel)
-            pmin, pmax = ops.page_minmax(kc, page_size=ps)
+            pmin, pmax = ops.page_minmax(kv_view(kc), page_size=ps)
             pmin = pmin.max(axis=2)  # reduce kv-head dim for the bound
             pmax = pmax.max(axis=2)
         with jax.named_scope("relevancy"):

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig, MemoryConfig
 from repro.core.pipeline import MemoryPipeline
 from repro.kernels import ops
+from repro.kernels.page_pool import kv_view
 from repro.models import layers as L
 
 Params = Dict
@@ -57,7 +58,7 @@ def make_sparse_fn(cfg: ArchConfig, mem: MemoryConfig, *, tp: int = 16):
         S = kc.shape[1]
         with jax.named_scope("prepare"):
             # pooled block keys
-            k_gate = (kc.reshape(B, S, -1) @ sp["wk_gate"])
+            k_gate = (kv_view(kc).reshape(B, S, -1) @ sp["wk_gate"])
             k_blk = k_gate.reshape(B, S // bs, bs, -1).mean(axis=2)
         with jax.named_scope("relevancy"):
             # gated query; the kernel fuses the top-k blocks in

@@ -70,8 +70,11 @@ def relevancy_topk(q, keys, weights, k: int, *, block: int = 2048,
 
 def paged_decode_attention(q, k_cache, v_cache, page_ids, length, *,
                            page_size: int = 64):
+    """Attention over the selected pages of a view or a ``PooledKV``
+    (``kernels/page_pool.py``) -> (out, lse)."""
     if not _STATE["pallas"]:
-        return ref.paged_decode_attention(q, k_cache, v_cache, page_ids,
+        return ref.paged_decode_attention(q, _pp.kv_view(k_cache),
+                                          _pp.kv_view(v_cache), page_ids,
                                           page_size, length)
     return _sda.paged_decode_attention(q, k_cache, v_cache, page_ids, length,
                                        page_size=page_size,

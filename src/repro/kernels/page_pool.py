@@ -12,10 +12,12 @@ Two groups of device code live here:
   gather's or scatter's indices: no ``[n_pages, page_size, KV, dh]`` slice
   of a layer is ever materialized, and the scatter updates the carried
   pool in place. ``pool_gather`` materializes a contiguous per-slot view of
-  one layer (an advanced-indexing gather); every decode path's attention
-  (the dense fallback and the paged Pallas kernel in
-  ``sparse_decode_attention.py``, which picks selected pages out of that
-  view) reads the view, not the pool.
+  one layer (an advanced-indexing gather). The pooled decode step hands
+  its attention a ``PooledKV`` (pool, layer, table) in place of that view:
+  the paged Pallas kernel in ``sparse_decode_attention.py`` reads the
+  selected pages straight from the pool, and only readers that need every
+  cached token (the dense fallback, an indexer's key projection) build the
+  view, with ``kv_view``. Chunked prefill still reads the view.
 
 * ``page_minmax``: the LServe Prepare-Memory stage. Each logical page of the
   key cache is summarized by its channel-wise min and max vectors; the
@@ -25,6 +27,7 @@ Two groups of device code live here:
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +52,47 @@ def pool_gather(pages: jnp.ndarray, layer, page_table: jnp.ndarray
     B, NP = page_table.shape
     view = pages[layer, page_table]               # [B, NP, ps, KV, dh]
     return view.reshape(B, NP * ps, KV, dh)
+
+
+class PooledKV(NamedTuple):
+    """One layer of the stacked pool as the slots address it, standing in
+    for the view ``pool_gather(pages, layer, table)`` [B, NP * ps, KV, dh]
+    without building it. ``shape`` and ``dtype`` are the view's."""
+    pages: jnp.ndarray          # [L, P, ps, KV, dh]
+    layer: jnp.ndarray          # int32 scalar (may be traced)
+    table: jnp.ndarray          # [B, NP] int32
+
+    @property
+    def shape(self):
+        _, _, ps, kv, dh = self.pages.shape
+        B, NP = self.table.shape
+        return (B, NP * ps, kv, dh)
+
+    @property
+    def dtype(self):
+        return self.pages.dtype
+
+
+def kv_view(kv) -> jnp.ndarray:
+    """The contiguous per-slot view [B, S, KV, dh] of a view (returned as
+    it is) or of a ``PooledKV`` (gathered, in the ``retrieve`` stage)."""
+    if isinstance(kv, PooledKV):
+        with jax.named_scope("retrieve"):
+            return pool_gather(kv.pages, kv.layer, kv.table)
+    return kv
+
+
+def as_pool(kv, page_size: int) -> PooledKV:
+    """A ``PooledKV`` as it is, or a view [B, S, KV, dh] as a one-layer
+    pool of ``B * S / page_size`` pages with the identity table
+    ``b * NP + p`` (a reshape, no copy)."""
+    if isinstance(kv, PooledKV):
+        return kv
+    B, S, KV, dh = kv.shape
+    NP = S // page_size
+    assert NP * page_size == S, (S, page_size)
+    return PooledKV(kv.reshape(1, B * NP, page_size, KV, dh), jnp.int32(0),
+                    jnp.arange(B * NP, dtype=jnp.int32).reshape(B, NP))
 
 
 def pool_gather_rows(pages: jnp.ndarray, layer, page_table: jnp.ndarray,

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.kernels.page_pool import kv_view
 from repro.models import layers as L
 
 Params = Dict[str, jnp.ndarray]
@@ -171,7 +172,9 @@ def attention_decode(
     window: Optional[int] = None,
     tp: int = 16,
 ) -> jnp.ndarray:
-    """q [B,1,Hp,hd]; caches [B,Smax,KV,hd]; length [] or [B] -> [B,1,Hp,hd]."""
+    """q [B,1,Hp,hd]; caches [B,Smax,KV,hd] (or ``PooledKV``, gathered
+    here); length [] or [B] -> [B,1,Hp,hd]."""
+    k_cache, v_cache = kv_view(k_cache), kv_view(v_cache)
     B, Smax = k_cache.shape[0], k_cache.shape[1]
     hd = q.shape[-1]
     window = window if window is not None else (cfg.sliding_window or None)

@@ -532,9 +532,12 @@ def decode_step_paged(params, cfg: ArchConfig, token, pool, live, *,
     scalar. Returns (logits [B, V], pool') with live lengths advanced by one.
 
     The layer loop carries the whole stacked pool: each layer writes its
-    token into the carried ``[L, P, ps, KV, dh]`` pages and gathers its view
-    from them by layer index, so no layer's pages are sliced out or
-    restacked, and with the pool donated the step updates it in place.
+    token into the carried ``[L, P, ps, KV, dh]`` pages and hands its
+    attention the pool, the layer index and the page table
+    (``PooledKV``) in place of a view, so no layer's pages are sliced out or
+    restacked, and with the pool donated the step updates it in place. The
+    paged kernel reads the selected pages from the pool; a reader that needs
+    every cached token gathers the view itself (``kv_view``).
 
     A latent-attention config runs ``mla.decode_step_paged``, whose own
     indexer (``sparse_params``) replaces ``sparse_fn``.
@@ -542,7 +545,7 @@ def decode_step_paged(params, cfg: ArchConfig, token, pool, live, *,
     if cfg.is_mla:
         return mla.decode_step_paged(params, cfg, token, pool, live,
                                      sparse_params=sparse_params)
-    from repro.kernels.page_pool import pool_gather, pool_scatter, token_dest
+    from repro.kernels.page_pool import PooledKV, pool_scatter, token_dest
 
     B = token.shape[0]
     lengths = pool["lengths"]
@@ -563,9 +566,7 @@ def decode_step_paged(params, cfg: ArchConfig, token, pool, live, *,
         with jax.named_scope("kv_write"):
             kp = pool_scatter(kp, layer, dest, k[:, 0])
             vp = pool_scatter(vp, layer, dest, v[:, 0])
-        with jax.named_scope("retrieve"):
-            kc = pool_gather(kp, layer, table)
-            vc = pool_gather(vp, layer, table)
+        kc, vc = PooledKV(kp, layer, table), PooledKV(vp, layer, table)
         if sparse_fn is not None:
             res = sparse_fn(q, kc, vc, lengths + 1, sp, k_new=k)
             attn = res[0] if isinstance(res, tuple) else res

@@ -1,8 +1,9 @@
 """Compile rehearsal of the main-path Pallas kernels for a TPU v5e.
 
 Each kernel is compiled at Qwen3-32B widths (64 query heads / 8 KV,
-head_dim 128, 32k context, DSA indexer 64 x 128 with relevancy block 2048)
-for a DESCRIBED v5e — the TPU compiler is installed, the chip is not — and
+head_dim 128, 32k context, DSA indexer 64 x 128 with relevancy block 2048),
+and the paged kernel's pool form also at Qwen2-7B widths (28 / 4), for a
+DESCRIBED v5e — the TPU compiler is installed, the chip is not — and
 the program must hold the Mosaic kernel (``tpu_custom_call``). This catches
 what interpret mode cannot: block shapes Mosaic refuses, primitives it
 cannot lower, VMEM overuse. Nothing runs, so results are not checked here
@@ -13,14 +14,18 @@ one process may load the TPU library, and every pytest-xdist worker
 imports this file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import bm25_topk, flash_attention, page_pool, \
     relevancy_topk, sparse_decode_attention
+
+from hlo_checks import DSA_VIEW_OPS, view_ops
 
 B, S, HQ, KV, DH = 2, 32768, 64, 8, 128     # qwen3-32b heads, 32k context
 PAGE, N_SEL = 16, 2048 // 16 + 1            # DSA micro-page, top-k + recency
@@ -60,6 +65,25 @@ def _paged(sds):
                 sds((B, N_SEL), jnp.int32), sds((B,), jnp.int32))
 
 
+def _paged_pool(kv, g, n_layers):
+    """The pool form at a cell's widths: a stacked pool of 4,097 pages of
+    16 (two 32k slots and the zero page), a traced layer index, the page
+    table and the selection."""
+    pool_pages = B * S // PAGE + 1
+
+    def build(sds):
+        def fn(q, kp, vp, layer, table, pids, length):
+            return sparse_decode_attention.paged_decode_attention(
+                q, page_pool.PooledKV(kp, layer, table),
+                page_pool.PooledKV(vp, layer, table), pids, length,
+                page_size=PAGE, interpret=False)
+        pool = sds((n_layers, pool_pages, PAGE, kv, DH), jnp.bfloat16)
+        return fn, (sds((B, kv * g, DH), jnp.bfloat16), pool, pool,
+                    sds((), jnp.int32), sds((B, S // PAGE), jnp.int32),
+                    sds((B, N_SEL), jnp.int32), sds((B,), jnp.int32))
+    return build
+
+
 def _relevancy(sds):
     n_pages = S // PAGE
     fn = functools.partial(relevancy_topk.relevancy_topk_candidates,
@@ -92,14 +116,73 @@ def _flash(sds):
 
 
 @pytest.mark.parametrize("build", [_paged, _relevancy, _bm25, _minmax,
-                                   _flash],
+                                   _flash, _paged_pool(KV, HQ // KV, 4),
+                                   _paged_pool(4, 7, 7)],
                          ids=["paged_decode_attention",
                               "relevancy_topk_candidates",
                               "bm25_topk_candidates", "page_minmax",
-                              "flash_attention"])
+                              "flash_attention",
+                              "paged_decode_attention_pool_qwen3_32b",
+                              "paged_decode_attention_pool_qwen2_7b"])
 def test_kernel_compiles_for_v5e(one_chip, build):
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
                                                  sharding=one_chip)
     fn, args = build(sds)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _view_sized(hlo, n):
+    """Opcodes of the instructions whose result holds ``n`` elements."""
+    ops_ = []
+    for m in re.finditer(r"= \w+\[([\d,]+)\]\S* ([\w-]+)\(", hlo):
+        if int(np.prod([int(d) for d in m.group(1).split(",")])) == n:
+            ops_.append(m.group(2))
+    return ops_
+
+
+def test_dsa_decode_step_reads_the_pool_for_v5e(one_chip, monkeypatch):
+    """The pooled decode step with the engine's DSA ``sparse_fn``, at smoke
+    widths with the Mosaic kernel: the kernel reads the carried pool, so the
+    program holds no pool-sized copy, slice or update-slice, three
+    view-sized gathers (K and V in the dense fallback, K for the index
+    projection: one outside the dense fallback) and no head-major copy of
+    a view."""
+    from repro.configs import get_arch
+    from repro.kernels import ops
+    from repro.models import init_params
+    from repro.models import model as M
+    from repro.serving import Engine, ServeConfig
+
+    cfg = get_arch("llama3.2-1b").smoke()
+    B, max_len, ps = 2, 64, 16
+    eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(0), tp=4),
+                 ServeConfig(max_len=max_len, n_slots=B, method="dsa", tp=4,
+                             page=ps, kv_page_size=ps),
+                 key=jax.random.PRNGKey(0))
+    monkeypatch.setattr(ops, "_interp", lambda: False)
+    on = lambda t: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), t)
+    pool = on(jax.eval_shape(lambda: M.make_page_pool(
+        cfg, B, max_len, page_size=ps, total_pages=B * max_len // ps + 1,
+        tp=4)))
+
+    def decode(p, tok, kp, vp, table, lengths, live, sp):
+        return M.decode_step_paged(
+            p, cfg, tok, {"k_pages": kp, "v_pages": vp, "page_table": table,
+                          "lengths": lengths}, live, tp=4,
+            sparse_fn=eng._sparse_fn, sparse_params=sp)
+
+    vec = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    hlo = jax.jit(decode, donate_argnums=(2, 3)).lower(
+        on(eng.params), vec, pool["k_pages"], pool["v_pages"],
+        pool["page_table"], vec,
+        jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip),
+        on(eng.sparse_params)).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    L, P, _, kv, dh = pool["k_pages"].shape
+    for n in (L * P * ps * kv * dh, P * ps * kv * dh):   # pool, one layer
+        bad = {"copy", "dynamic-slice", "dynamic-update-slice"}
+        assert not bad & set(_view_sized(hlo, n))
+    assert view_ops(hlo, pool["k_pages"].shape, B,
+                    max_len // ps) == DSA_VIEW_OPS

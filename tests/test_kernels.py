@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.page_pool import PooledKV, pool_gather
 
 RNG = np.random.default_rng(42)
 
@@ -58,13 +59,48 @@ def test_relevancy_topk_approximate_recall():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("B,KV,G,dh,S,ps,nsel", [
-    (1, 1, 1, 32, 128, 16, 4),
-    (2, 2, 4, 64, 512, 16, 8),
-    (2, 8, 8, 128, 1024, 64, 8),    # GQA 64 heads / 8 kv
+def _pool_form(kc, vc, pool_page, n_layers=3, layer=1):
+    """The views as layer ``layer`` of a pool of ``n_layers`` layers: pages
+    of ``pool_page`` tokens behind a shuffled page table, every third entry
+    unallocated (on the zero page), other layers and spare pages random.
+    -> (pool K, pool V, table, the views the pool gives)."""
+    B, S, KV, dh = kc.shape
+    NP = S // pool_page
+    P = B * NP + 4                                   # zero page + spares
+    table = RNG.permutation(np.arange(1, P))[:B * NP].reshape(B, NP)
+    table[:, ::3] = 0                                # unallocated entries
+
+    def pool(view):
+        pages = np.asarray(RNG.standard_normal(
+            (n_layers, P, pool_page, KV, dh)), np.float32)
+        pages[layer, table] = np.asarray(view, np.float32).reshape(
+            B, NP, pool_page, KV, dh)
+        pages[:, 0] = 0                              # the zero page
+        return jnp.asarray(pages, kc.dtype)
+
+    kp, vp = pool(kc), pool(vc)
+    table = jnp.asarray(table, jnp.int32)
+    return kp, vp, table, (pool_gather(kp, layer, table),
+                           pool_gather(vp, layer, table))
+
+
+@pytest.mark.parametrize("B,KV,G,dh,S,ps,nsel,pool_page", [
+    pytest.param(1, 1, 1, 32, 128, 16, 4, None, id="1-1-1-32-128-16-4"),
+    pytest.param(2, 2, 4, 64, 512, 16, 8, None, id="2-2-4-64-512-16-8"),
+    pytest.param(2, 8, 8, 128, 1024, 64, 8, None,     # GQA 64 heads / 8 kv
+                 id="2-8-8-128-1024-64-8"),
+    # the pool form: pages read straight from a stacked pool
+    pytest.param(2, 8, 8, 128, 512, 16, 9, 16, id="pool-kv8-g8"),
+    pytest.param(2, 4, 7, 128, 512, 16, 9, 16, id="pool-kv4-g7"),
+    pytest.param(2, 2, 4, 64, 256, 8, 6, 16, id="pool-select8-of-16"),
+    pytest.param(2, 2, 4, 64, 256, 32, 4, 16, id="pool-select32-of-16"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_attention(B, KV, G, dh, S, ps, nsel, dtype):
+def test_paged_decode_attention(B, KV, G, dh, S, ps, nsel, pool_page, dtype):
+    """Against the oracle over the contiguous view; in the pool form the
+    kernel reads the pool under a traced layer index and is compared with
+    the oracle over the view ``pool_gather`` builds (zero-page entries
+    included), with selected pages smaller and larger than a pool page."""
     Hq = KV * G
     q = _arr((B, Hq, dh), dtype)
     kc = _arr((B, S, KV, dh), dtype)
@@ -74,7 +110,15 @@ def test_paged_decode_attention(B, KV, G, dh, S, ps, nsel, dtype):
         jnp.int32)
     pages = pages.at[0, -1].set(-1)  # invalid page masking
     length = jnp.asarray(RNG.integers(S // 2, S + 1, B), jnp.int32)
-    o1, l1 = ops.paged_decode_attention(q, kc, vc, pages, length, page_size=ps)
+    if pool_page is None:
+        o1, l1 = ops.paged_decode_attention(q, kc, vc, pages, length,
+                                            page_size=ps)
+    else:
+        pages = pages.at[1, 0].set(-1)
+        kp, vp, table, (kc, vc) = _pool_form(kc, vc, pool_page)
+        o1, l1 = jax.jit(lambda layer: ops.paged_decode_attention(
+            q, PooledKV(kp, layer, table), PooledKV(vp, layer, table), pages,
+            length, page_size=ps))(jnp.int32(1))
     o2, l2 = ref.paged_decode_attention(q, kc, vc, pages, ps, length)
     tol = 1e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=tol, atol=tol)

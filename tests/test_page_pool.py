@@ -8,7 +8,8 @@ with a layer index inside each gather and scatter:
   * the compiled decode and chunked-prefill programs, with the pool donated
     as the engine donates it, neither slice a layer out of the pool nor
     restack or copy it: the layer loop carries the pool and updates it in
-    place.
+    place; with DSA the sparse branch gathers one view (K, for the index
+    projection) and the paged kernel reads the pool.
 """
 import re
 
@@ -18,10 +19,14 @@ import numpy as np
 import pytest
 
 from repro.configs import get_arch
-from repro.kernels.page_pool import (pool_gather, pool_scatter, span_dest,
-                                     token_dest)
+from repro.kernels import ops
+from repro.kernels.page_pool import (PooledKV, pool_gather, pool_scatter,
+                                     span_dest, token_dest)
 from repro.models import init_params
 from repro.models import model as M
+from repro.serving import Engine, ServeConfig
+
+from hlo_checks import DSA_VIEW_OPS, view_ops
 
 L, P, PS, KV, DH = 3, 9, 4, 2, 8
 B, NP, C = 3, 4, 5
@@ -155,17 +160,31 @@ def _smoke():
     return cfg, params, pool
 
 
-def _decode_hlo(cfg, params, pool):
-    def decode_paged(p, tok, kp, vp, table, lengths, live):
+def _decode_hlo(cfg, params, pool, sparse_fn=None, sp=None):
+    def decode_paged(p, tok, kp, vp, table, lengths, live, sp):
         return M.decode_step_paged(
             p, cfg, tok, {"k_pages": kp, "v_pages": vp, "page_table": table,
-                          "lengths": lengths}, live, tp=4)
+                          "lengths": lengths}, live, tp=4,
+            sparse_fn=sparse_fn, sparse_params=sp)
 
     B = pool["lengths"].shape[0]
     return jax.jit(decode_paged, donate_argnums=(2, 3)).lower(
         params, jax.ShapeDtypeStruct((B,), jnp.int32), pool["k_pages"],
         pool["v_pages"], pool["page_table"], pool["lengths"],
-        jax.ShapeDtypeStruct((B,), jnp.bool_)).compile().as_text()
+        jax.ShapeDtypeStruct((B,), jnp.bool_), sp).compile().as_text()
+
+
+def _dsa_decode_hlo(cfg, params, pool):
+    """The decode program with the engine's DSA ``sparse_fn``: the sparse
+    branch and the dense fallback under one cond."""
+    B, NP = pool["page_table"].shape
+    ps = pool["k_pages"].shape[2]
+    sc = ServeConfig(max_len=NP * ps, n_slots=B, method="dsa", tp=4, page=ps,
+                     kv_page_size=ps)
+    eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(0), tp=4), sc,
+                 key=jax.random.PRNGKey(0))
+    return _decode_hlo(cfg, params, pool, eng._sparse_fn,
+                       jax.eval_shape(lambda: eng.sparse_params))
 
 
 def _extend_hlo(cfg, params, pool):
@@ -181,10 +200,30 @@ def _extend_hlo(cfg, params, pool):
         pool["lengths"]).compile().as_text()
 
 
-@pytest.mark.parametrize("program", ["decode", "extend"])
-def test_layer_loop_keeps_the_pool_in_place(program):
+@pytest.mark.parametrize("program", ["decode", "extend", "dsa"])
+def test_layer_loop_keeps_the_pool_in_place(program, monkeypatch):
+    """With the DSA ``sparse_fn`` the sparse branch hands the paged kernel
+    the pool: only the index projection gathers a (K) view, and no view is
+    copied head-major; the dense fallback gathers K and V. On the CPU the
+    kernel would run in interpret mode, whose block emulation copies its
+    operands, so here it is a stand-in that takes the ``PooledKV`` handles;
+    test_chip_compile.py checks the same program with the Mosaic kernel."""
+    handed = []
+
+    def kernel(q, kc, vc, page_ids, length, *, page_size):
+        handed.append((kc, vc))
+        picked = page_ids.sum(axis=1).astype(jnp.float32)  # keeps prepare
+        return (jnp.broadcast_to(picked[:, None, None], q.shape),
+                jnp.broadcast_to(picked[:, None], q.shape[:2]))
+
+    monkeypatch.setattr(ops, "paged_decode_attention", kernel)
     cfg, params, pool = _smoke()
-    hlo = {"decode": _decode_hlo, "extend": _extend_hlo}[program](
-        cfg, params, pool)
+    hlo = {"decode": _decode_hlo, "extend": _extend_hlo,
+           "dsa": _dsa_decode_hlo}[program](cfg, params, pool)
+    assert all(isinstance(kv, PooledKV) for pair in handed for kv in pair)
+    assert len(handed) == (program == "dsa")
     assert "scatter(" in hlo
     assert _pool_ops(hlo, pool["k_pages"].shape) == []
+    if program == "dsa":
+        assert view_ops(hlo, pool["k_pages"].shape,
+                        *pool["page_table"].shape) == DSA_VIEW_OPS

@@ -30,18 +30,26 @@ def _drain(eng, n_steps):
     return got
 
 
-@pytest.mark.parametrize("method", ["none", "dsa"])
+@pytest.mark.parametrize("method", ["none", "dsa", "dsa-sparse"])
 def test_pooled_decode_matches_per_request_generate(setup, method):
     """Mixed-length slots (incl. a ragged non-pow2 prompt) admitted through
-    the bucketed batched prefill decode EXACTLY like per-request generate."""
+    the bucketed batched prefill decode EXACTLY like per-request generate.
+    ``dsa`` selects pages of 8 inside pool pages of 16; ``dsa-sparse``
+    selects 2 pages of 16 from every context past 16 tokens, so each step
+    takes the sparse branch, whose kernel reads the pool (pooled) or the
+    view (per request)."""
     cfg, params = setup
-    sc = ServeConfig(max_len=96, n_slots=3, method=method, tp=4, page=8,
-                     kv_page_size=16)
-    eng = Engine(cfg, params, sc, key=jax.random.PRNGKey(0))
-    ref = Engine(cfg, params, sc, key=jax.random.PRNGKey(0))
+    mem, page, lens = None, 8, (16, 32, 9)
+    if method == "dsa-sparse":
+        mem = cfg.memory.replace(method="dsa", min_context=16, top_k=32)
+        page, lens = 16, (16, 40, 20)
+    sc = ServeConfig(max_len=96, n_slots=3, method=method.split("-")[0],
+                     tp=4, page=page, kv_page_size=16)
+    eng = Engine(cfg, params, sc, key=jax.random.PRNGKey(0), mem=mem)
+    ref = Engine(cfg, params, sc, key=jax.random.PRNGKey(0), mem=mem)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
-               for n in (16, 32, 9)]
+               for n in lens]
     max_new = 6
     refs = [ref.generate(jnp.asarray(p)[None], max_new)[0] for p in prompts]
     hs = [eng.submit(Request(i, p, max_new)) for i, p in enumerate(prompts)]
