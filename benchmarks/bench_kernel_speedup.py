@@ -19,7 +19,7 @@ from repro.kernels import ops, ref
 def run():
     # run.py keeps going after a failed bench, so the use_pallas(False)
     # below must be undone even on exceptions — later benches in the same
-    # process (e2e, batch_scaling, hetero_overlap) need the kernels back.
+    # process (e2e, hetero_overlap) need the kernels back.
     prev = ops.pallas_enabled()
     try:
         return _run()

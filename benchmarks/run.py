@@ -24,9 +24,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmarks import common
 from repro.launch.compile_cache import enable_compile_cache
 from benchmarks import (bench_memory_fraction, bench_kernel_speedup,
-                        bench_e2e, bench_energy, bench_batch_scaling,
-                        bench_comm_bytes, bench_hetero_overlap,
-                        bench_hetero_sharded, bench_retrieval,
+                        bench_e2e, bench_energy, bench_comm_bytes,
+                        bench_hetero_overlap, bench_hetero_sharded,
+                        bench_retrieval,
                         bench_main_mesh, bench_fused_decode, bench_router)
 
 BENCHES = [
@@ -34,7 +34,6 @@ BENCHES = [
     ("kernel_speedup (Fig 9/10r)", bench_kernel_speedup),
     ("e2e_speedup (Fig 8/10l/11/12)", bench_e2e),
     ("energy (Table 3)", bench_energy),
-    ("batch_scaling (Table 4)", bench_batch_scaling),
     ("comm_bytes (App C.1/Fig 16)", bench_comm_bytes),
     ("hetero_overlap (§5.3 offload)", bench_hetero_overlap),
     ("hetero_sharded (Fig 6a per-shard offload)", bench_hetero_sharded),

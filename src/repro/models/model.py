@@ -519,6 +519,54 @@ def make_page_pool(cfg: ArchConfig, n_slots: int, max_len: int, *,
     }
 
 
+def _paged_layers(params, cfg: ArchConfig, x, cos, sin, pool, dest, attend,
+                  xs, tp: int):
+    """The layer loop of every pooled program over the stacked pool.
+
+    Each layer writes its k and v into the carried ``[L, P, ps, KV, dh]``
+    pages at ``dest`` (``token_dest`` or ``span_dest``) and calls
+    ``attend(q, k, kc, vc, xs_l) -> (attn, out_l)`` with ``PooledKV``
+    handles (pool, layer index, page table) in place of views, so no
+    layer's pages are sliced out or restacked and a donated pool is updated
+    in place. ``xs`` is the per-layer input (stacked over layers, or None).
+    The carry keeps the residual first: a trace tells decode from prefill
+    by its rows. Returns (x, pool with the new pages, out_l stacked over
+    layers).
+    """
+    from repro.kernels.page_pool import PooledKV, pool_scatter
+
+    table = pool["page_table"]
+    per_token = dest[0].ndim == 1      # token_dest: write k[:, 0], not k
+
+    def layer_fn(carry, layer_xs):
+        x, kp, vp = carry
+        lp, xs_l, layer = layer_xs
+        q, k, v = _qkv(lp, x, cos, sin, cfg, tp)
+        with jax.named_scope("kv_write"):
+            kp = pool_scatter(kp, layer, dest, k[:, 0] if per_token else k)
+            vp = pool_scatter(vp, layer, dest, v[:, 0] if per_token else v)
+        attn, out = attend(q, k, PooledKV(kp, layer, table),
+                           PooledKV(vp, layer, table), xs_l)
+        return (_out_mlp(lp, x, attn, cfg, tp), kp, vp), out
+
+    (x, kp, vp), outs = jax.lax.scan(
+        layer_fn, (x, pool["k_pages"], pool["v_pages"]),
+        (params["layers"], xs, jnp.arange(cfg.n_layers)))
+    return x, dict(pool, k_pages=kp, v_pages=vp), outs
+
+
+def _paged_decode_inputs(params, cfg: ArchConfig, token, lengths,
+                         positions3=None):
+    """A pooled decode step's embedded token and its rope tables at each
+    slot's own position."""
+    with jax.named_scope("dense"):
+        x = L.embed(params["embed"], token[:, None])
+    if cfg.rope_style == "mrope" and positions3 is None:
+        positions3 = jnp.broadcast_to(lengths[None, :, None],
+                                      (3, token.shape[0], 1))
+    return (x,) + _rope_tables(cfg, lengths[:, None], positions3)
+
+
 def decode_step_paged(params, cfg: ArchConfig, token, pool, live, *,
                       tp: int = 16, sparse_fn=None, sparse_params=None,
                       positions3=None):
@@ -531,12 +579,8 @@ def decode_step_paged(params, cfg: ArchConfig, token, pool, live, *,
     fallback cond sees the true max over live slots instead of a shared
     scalar. Returns (logits [B, V], pool') with live lengths advanced by one.
 
-    The layer loop carries the whole stacked pool: each layer writes its
-    token into the carried ``[L, P, ps, KV, dh]`` pages and hands its
-    attention the pool, the layer index and the page table
-    (``PooledKV``) in place of a view, so no layer's pages are sliced out or
-    restacked, and with the pool donated the step updates it in place. The
-    paged kernel reads the selected pages from the pool; a reader that needs
+    Attention gets the pool, not a view (``_paged_layers``): the paged
+    kernel reads the selected pages from the pool; a reader that needs
     every cached token gathers the view itself (``kv_view``).
 
     A latent-attention config runs ``mla.decode_step_paged``, whose own
@@ -545,44 +589,29 @@ def decode_step_paged(params, cfg: ArchConfig, token, pool, live, *,
     if cfg.is_mla:
         return mla.decode_step_paged(params, cfg, token, pool, live,
                                      sparse_params=sparse_params)
-    from repro.kernels.page_pool import PooledKV, pool_scatter, token_dest
+    from repro.kernels.page_pool import token_dest
 
-    B = token.shape[0]
     lengths = pool["lengths"]
-    table = pool["page_table"]
     live = live.astype(bool)
-    with jax.named_scope("dense"):
-        x = L.embed(params["embed"], token[:, None])
-    positions = lengths[:, None]                           # [B, 1] per-slot
-    if cfg.rope_style == "mrope" and positions3 is None:
-        positions3 = jnp.broadcast_to(lengths[None, :, None], (3, B, 1))
-    cos, sin = _rope_tables(cfg, positions, positions3)
-    dest = token_dest(table, lengths, live, pool["k_pages"].shape[2])
+    x, cos, sin = _paged_decode_inputs(params, cfg, token, lengths,
+                                       positions3)
+    dest = token_dest(pool["page_table"], lengths, live,
+                      pool["k_pages"].shape[2])
 
-    def layer_fn(carry, xs):
-        x, kp, vp = carry
-        lp, sp, layer = xs
-        q, k, v = _qkv(lp, x, cos, sin, cfg, tp)
-        with jax.named_scope("kv_write"):
-            kp = pool_scatter(kp, layer, dest, k[:, 0])
-            vp = pool_scatter(vp, layer, dest, v[:, 0])
-        kc, vc = PooledKV(kp, layer, table), PooledKV(vp, layer, table)
-        if sparse_fn is not None:
-            res = sparse_fn(q, kc, vc, lengths + 1, sp, k_new=k)
-            attn = res[0] if isinstance(res, tuple) else res
-        else:
+    def attend(q, k, kc, vc, sp):
+        if sparse_fn is None:
             with jax.named_scope("apply"):
-                attn = A.attention_decode(q, kc, vc, lengths + 1, cfg, tp=tp)
-        return (_out_mlp(lp, x, attn, cfg, tp), kp, vp), None
+                return A.attention_decode(q, kc, vc, lengths + 1, cfg,
+                                          tp=tp), None
+        res = sparse_fn(q, kc, vc, lengths + 1, sp, k_new=k)
+        return (res[0] if isinstance(res, tuple) else res), None
 
     sp_stack = sparse_params
     if sp_stack is None:
         sp_stack = jnp.zeros((cfg.n_layers,), jnp.int32)   # dummy scan leaf
-    (x, k_new, v_new), _ = jax.lax.scan(
-        layer_fn, (x, pool["k_pages"], pool["v_pages"]),
-        (params["layers"], sp_stack, jnp.arange(cfg.n_layers)))
-    pool = dict(pool, k_pages=k_new, v_pages=v_new,
-                lengths=lengths + live.astype(jnp.int32))
+    x, pool, _ = _paged_layers(params, cfg, x, cos, sin, pool, dest, attend,
+                               sp_stack, tp)
+    pool = dict(pool, lengths=lengths + live.astype(jnp.int32))
     return _logits(params, cfg, x), pool
 
 
@@ -625,36 +654,21 @@ def decode_step_paged_presel(params, cfg: ArchConfig, token, pool, live,
     from repro.core import placement
     from repro.core.methods.dsa import strip_dead_heads, repad_dead_heads
     from repro.kernels import ops
-    from repro.kernels.page_pool import pool_gather, pool_scatter, token_dest
+    from repro.kernels.page_pool import kv_view, token_dest
 
     B = token.shape[0]
     ps = page_size
     lengths = pool["lengths"]
-    table = pool["page_table"]
     live = live.astype(bool)
-    with jax.named_scope("dense"):
-        x = L.embed(params["embed"], token[:, None])
-    positions = lengths[:, None]
-    positions3 = None
-    if cfg.rope_style == "mrope":
-        positions3 = jnp.broadcast_to(lengths[None, :, None], (3, B, 1))
-    cos, sin = _rope_tables(cfg, positions, positions3)
-
+    x, cos, sin = _paged_decode_inputs(params, cfg, token, lengths)
     lb = lengths + 1                       # context incl. this step's token
     cur_page = lengths // ps               # page receiving this step's write
     use_sparse = placement.traced_use_sparse(lb, mem)
-    dest = token_dest(table, lengths, live, pool["k_pages"].shape[2])
+    dest = token_dest(pool["page_table"], lengths, live,
+                      pool["k_pages"].shape[2])
 
-    def layer_fn(carry, xs):
-        x, kp, vp = carry
-        lp, sel, layer = xs
-        q, k, v = _qkv(lp, x, cos, sin, cfg, tp)
-        with jax.named_scope("kv_write"):
-            kp = pool_scatter(kp, layer, dest, k[:, 0])
-            vp = pool_scatter(vp, layer, dest, v[:, 0])
-        with jax.named_scope("retrieve"):
-            kc = pool_gather(kp, layer, table)
-            vc = pool_gather(vp, layer, table)
+    def attend(q, k, kc, vc, sel):
+        kc, vc = kv_view(kc), kv_view(vc)
 
         def sparse(_):
             with jax.named_scope("retrieve"):
@@ -683,13 +697,11 @@ def decode_step_paged_presel(params, cfg: ArchConfig, token, pool, live,
                 return repad_dead_heads(out, q, cfg)
 
         attn = jax.lax.cond(use_sparse, sparse, dense, None)
-        return (_out_mlp(lp, x, attn, cfg, tp), kp, vp), (q[:, 0], k[:, 0])
+        return attn, (q[:, 0], k[:, 0])
 
-    (x, k_new, v_new), (q_layers, k_layers) = jax.lax.scan(
-        layer_fn, (x, pool["k_pages"], pool["v_pages"]),
-        (params["layers"], pidx, jnp.arange(cfg.n_layers)))
-    pool = dict(pool, k_pages=k_new, v_pages=v_new,
-                lengths=lengths + live.astype(jnp.int32))
+    x, pool, (q_layers, k_layers) = _paged_layers(
+        params, cfg, x, cos, sin, pool, dest, attend, pidx, tp)
+    pool = dict(pool, lengths=lengths + live.astype(jnp.int32))
     return _logits(params, cfg, x), pool, q_layers, k_layers
 
 
@@ -708,8 +720,6 @@ def extend_paged(params, cfg: ArchConfig, tokens, pool, n_valid, *,
     and q_last [L, B, Hp, hd] (the query at each row's last valid chunk
     token) — consumed by the hetero offload executor to keep its
     device-resident memory index coherent with the pool.
-    ``decode_step_paged`` is the C=1 specialization of this, kept separate
-    so the decode path can thread the sparse-method fallback.
 
     ``x_embeds [B, C, d]`` + ``emb_rows [B]`` feed rows with PRE-EMBEDDED
     context instead of token ids: the MaC retrieval service splices
@@ -722,11 +732,10 @@ def extend_paged(params, cfg: ArchConfig, tokens, pool, n_valid, *,
     if cfg.is_mla:
         return mla.extend_paged(params, cfg, tokens, pool, n_valid,
                                 sparse_params=sparse_params)
-    from repro.kernels.page_pool import pool_gather, pool_scatter, span_dest
+    from repro.kernels.page_pool import kv_view, span_dest
 
     B, C = tokens.shape
     lengths = pool["lengths"]
-    table = pool["page_table"]
     with jax.named_scope("dense"):
         x = L.embed(params["embed"], tokens)
         if x_embeds is not None:
@@ -737,32 +746,23 @@ def extend_paged(params, cfg: ArchConfig, tokens, pool, n_valid, *,
     if cfg.rope_style == "mrope":
         positions3 = jnp.broadcast_to(positions[None], (3, B, C))
     cos, sin = _rope_tables(cfg, positions, positions3)
-    dest = span_dest(table, lengths, n_valid, C, pool["k_pages"].shape[2])
+    dest = span_dest(pool["page_table"], lengths, n_valid, C,
+                     pool["k_pages"].shape[2])
 
-    def layer_fn(carry, xs):
-        x, kp, vp = carry
-        lp, layer = xs
-        q, k, v = _qkv(lp, x, cos, sin, cfg, tp)
-        with jax.named_scope("kv_write"):
-            kp = pool_scatter(kp, layer, dest, k)
-            vp = pool_scatter(vp, layer, dest, v)
-        with jax.named_scope("retrieve"):
-            kc = pool_gather(kp, layer, table)
-            vc = pool_gather(vp, layer, table)
+    def attend(q, k, kc, vc, _):
+        kc, vc = kv_view(kc), kv_view(vc)
         with jax.named_scope("apply"):
             attn = A.attention_decode_chunk(q, kc, vc, lengths, cfg, tp=tp)
-        x = _out_mlp(lp, x, attn, cfg, tp)
-        return (x, kp, vp), ((k, q) if collect_kq else None)
+        return attn, ((k, q) if collect_kq else None)
 
-    (x, k_new, v_new), kq = jax.lax.scan(
-        layer_fn, (x, pool["k_pages"], pool["v_pages"]),
-        (params["layers"], jnp.arange(cfg.n_layers)))
+    x, pool, kq = _paged_layers(params, cfg, x, cos, sin, pool, dest, attend,
+                                None, tp)
     last = jnp.clip(n_valid - 1, 0, C - 1)
     with jax.named_scope("dense"):
         x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
         xg = jnp.take_along_axis(x, last[:, None, None], axis=1)  # [B,1,d]
         logits = L.lm_head(params["lm_head"], xg, cfg)[:, 0]
-    pool = dict(pool, k_pages=k_new, v_pages=v_new, lengths=lengths + n_valid)
+    pool = dict(pool, lengths=lengths + n_valid)
     if not collect_kq:
         return logits, pool
     k_span, q_span = kq

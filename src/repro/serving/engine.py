@@ -1,26 +1,28 @@
-"""Batched decode engine with the memory pipeline as a first-class feature.
+"""Serving engine: continuous batching over a paged KV pool, with the
+memory pipeline as a first-class feature.
 
-* builds jitted prefill / decode steps (optionally on separate role meshes —
-  the paper's prefill/decode disaggregation, Fig. 6b),
-* wires the sparse-attention memory pipeline into decode via the placement
-  policy: a traced lax.cond implements the paper's DYNAMIC FALLBACK — dense
-  attention below ``min_context`` and above ``fallback_context``, the fused
-  sparse pipeline in between (for pooled decode the cond is decided on the
-  max length over live slots; masks inside the branch stay per-slot),
-* continuous batching runs on a PAGED KV pool with PER-SLOT lengths: slots
-  allocate/free fixed-size pages at admit/release (HBM scales with live
-  tokens, not ``n_slots * max_len``), every slot decodes at its own RoPE
-  position / cache offset / attention mask, admission prefill is batched
-  over length buckets with a small set of pre-jitted shapes, and long
-  prompts prefill in fixed-size chunks interleaved with decode steps,
-* the legacy dense ``n_slots x max_len`` pool with the shared
-  ``lengths.max()`` watermark is kept behind ``ServeConfig(paged=False)`` as
-  the benchmark baseline (bench_batch_scaling old-vs-new comparison),
+* Every transformer-family request decodes in one pool of fixed-size KV
+  pages with PER-SLOT lengths: slots allocate/free pages at admit/release
+  (HBM scales with live tokens, not ``n_slots * max_len``), every slot
+  decodes at its own RoPE position / cache offset / attention mask,
+  admission prefill is batched over length buckets with a small set of
+  pre-jitted shapes, and long prompts prefill in fixed-size chunks
+  interleaved with decode steps (``models.decode_step_paged`` /
+  ``extend_paged`` / ``prefill_bucketed``).
+* A sparse method decodes through ``core.methods.make_decode_attention``:
+  the paper's DYNAMIC FALLBACK as a traced cond, dense attention outside
+  [``min_context``, ``fallback_context``] and the fused sparse pipeline
+  inside (decided on the max length over live slots; masks inside the
+  branch stay per-slot).
 * ``ServeConfig(offload_cfg=OffloadConfig(...))`` routes the
-  memory-processing stages through
-  the heterogeneous offload executor (src/repro/hetero): lookahead
-  selection on a second device, overlapped with decode, exchanging only
-  page indices — the paper's §5 system emulated on JAX devices.
+  memory-processing stages through the heterogeneous offload executor
+  (src/repro/hetero): lookahead selection on a second device, overlapped
+  with decode, exchanging only page indices — the paper's §5 system
+  emulated on JAX devices.
+* ``generate`` serves a prompt batch through the pool; the per-request
+  dense-cache loop (``models.prefill`` / ``decode_step``) is the reference
+  the pooled path is checked against, and the path of the families the
+  pool does not hold (ssm, hybrid).
 """
 from __future__ import annotations
 
@@ -37,8 +39,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig, MemoryConfig
-from repro.core import placement
-from repro.core.methods import get_sparse_method
+from repro.core.methods import get_sparse_method, make_decode_attention
 from repro.models import model as M
 from repro.serving.api import Request, ResponseHandle
 from repro.serving.events import StepEvents
@@ -106,7 +107,6 @@ class ServeConfig:
     page: int = 16             # dsa micro-page size
     greedy: bool = True
     # --- paged continuous batching ---
-    paged: bool = True         # False = legacy dense pool + shared watermark
     kv_page_size: int = 16     # physical KV page (pool granule)
     pool_pages: int = 0        # 0 = full backing; else arena size (oversubscribe)
     prefill_chunk: int = 128   # chunk span for chunked prefill
@@ -117,8 +117,8 @@ class ServeConfig:
     # "off" = inline sparse pipeline; "sync" = two-phase select->apply on
     # the offload device but serialized (validation/benchmark baseline);
     # "overlap" = double-buffered lookahead selection overlapped with
-    # decode (the paper's heterogeneous execution). Requires paged=True and
-    # a sparse method (dsa | seer | lserve).
+    # decode (the paper's heterogeneous execution). Requires a sparse
+    # method (dsa | seer | lserve).
     offload: str = "off"
     offload_validate: bool = False  # replay each consumed selection + check
     # >1 shards the offload side over one device per KV-sequence shard
@@ -143,7 +143,7 @@ class ServeConfig:
     # RAG doc splices / MaC memory-bank embedding splices through the
     # chunked-extend path, inline or on the offload device (sync/overlap).
     # Composes with ``offload`` — retrieval slots share the pool with
-    # sparse-attention slots. Requires paged=True.
+    # sparse-attention slots.
     retrieval: Optional[object] = None
     # --- redesigned stepping/config surface -----------------------------
     # ``offload_cfg`` is the first-class surface for the offload topology;
@@ -192,9 +192,6 @@ class ServeConfig:
         if self.fused_steps < 1:
             raise ValueError(
                 f"fused_steps must be >= 1, got {self.fused_steps}")
-        if self.fused_steps > 1 and not self.paged:
-            raise ValueError("fused_steps > 1 fuses the PAGED decode loop "
-                             "(ServeConfig(paged=True))")
 
 
 class Engine:
@@ -220,7 +217,7 @@ class Engine:
                    if sc.method == "lserve" else 1)
         if sc.method == "none":
             gran = 1
-        gran = math.lcm(gran, sc.kv_page_size if sc.paged else 1)
+        gran = math.lcm(gran, sc.kv_page_size)
         # sharded offload: every shard window must cover a whole number of
         # selection pages AND kv pages, so align max_len to gran * shards
         gran *= max(sc.offload_shards, 1)
@@ -239,44 +236,21 @@ class Engine:
             # latent attention serves on the inline paged path only, with
             # its own (published) indexer
             assert sc.method == "dsa", sc.method
-            assert sc.paged and sc.offload == "off" and sc.fused_steps == 1 \
+            assert sc.offload == "off" and sc.fused_steps == 1 \
                 and sc.retrieval is None, \
                 "latent attention runs inline, stepped, without retrieval"
         self.sparse_params = None
-        sparse_fn = None
+        self._sparse_fn = None
         if sc.method != "none" and cfg.family != "ssm":
-            init_fn, mk = get_sparse_method(sc.method)
+            init_fn, _ = get_sparse_method(sc.method)
             # jitted like init_params: no eager float32 draw of a stack
             self.sparse_params = jax.jit(
                 init_fn, static_argnums=(1, 2), static_argnames="stacked")(
                 key if key is not None else jax.random.PRNGKey(0),
                 cfg, self.mem, stacked=cfg.family != "hybrid")
-        if self.sparse_params is not None and not cfg.is_mla:
-            kw = {"page": sc.page} if sc.method == "dsa" else {}
-            raw = mk(cfg, self.mem, tp=sc.tp, **kw)
-            mem = self.mem
-
-            def fallback_fn(q, kc, vc, length, sp, k_new=None):
-                """Paper's dynamic fallback as a traced cond.
-
-                ``length`` is a scalar (per-request decode) or a per-slot
-                vector (pooled decode); the cond predicate is batch-level
-                (max over slots — a jitted cond cannot branch per row), the
-                branch itself masks per slot.
-                """
-                from repro.models import attention as A
-
-                def dense(_):
-                    return A.attention_decode(q, kc, vc, length, cfg, tp=sc.tp)
-
-                def sparse(_):
-                    return raw(q, kc, vc, length, sp, k_new=k_new)
-
-                use_sparse = placement.traced_use_sparse(length, mem)
-                return jax.lax.cond(use_sparse, sparse, dense, None)
-
-            sparse_fn = fallback_fn
-        self._sparse_fn = sparse_fn
+            if not cfg.is_mla:
+                self._sparse_fn = make_decode_attention(
+                    cfg, self.mem, sc.method, tp=sc.tp, page=sc.page)
 
         # --- main mesh (sequence-parallel apply) ---------------------------
         self.main_mesh = None
@@ -287,7 +261,6 @@ class Engine:
                 "Engine(devices=...) pins a replica's device group; it " \
                 "does not compose with main_mesh — the mesh picks its own " \
                 "devices (hetero.policy.pick_devices_mesh)"
-            assert sc.paged, "main_mesh shards the paged apply"
             assert sc.offload in ("sync", "overlap"), \
                 "main_mesh needs ServeConfig(offload='sync'|'overlap') — " \
                 "the sequence-parallel apply runs the two-phase presel step"
@@ -316,7 +289,6 @@ class Engine:
         self.hetero = None
         if sc.offload != "off":
             assert sc.offload in ("sync", "overlap"), sc.offload
-            assert sc.paged, "hetero offload runs over the paged pool"
             assert sc.method in ("dsa", "seer", "lserve"), \
                 "hetero offload needs a sparse memory-processing method"
             assert cfg.family in POOL_FAMILIES
@@ -339,7 +311,6 @@ class Engine:
 
         self.retrieval = None
         if sc.retrieval is not None:
-            assert sc.paged, "the retrieval subsystem serves the paged pool"
             assert cfg.family in POOL_FAMILIES
             from repro.retrieval import RetrievalExecutor
             rdevs = self.hetero.devices if self.hetero else None
@@ -384,7 +355,6 @@ class Engine:
 
         self.slots = SlotManager(sc.n_slots, sc.max_len)
         self.pool: Optional[PagedKVPool] = None
-        self.caches = None            # legacy dense pool
         # chunked-prefill state: slot -> [request_id, prompt np, next_pos]
         self._chunks: Dict[int, list] = {}
         # host_steps counts step_pool dispatch boundaries, decode_steps the
@@ -451,8 +421,8 @@ class Engine:
             req = self.queue[0]
             plen = len(req)
             # a latent-attention model prefills every prompt in chunks
-            chunked = self.sc.paged and (self.cfg.is_mla or bool(
-                req.override("chunked", plen > self.sc.chunk_threshold)))
+            chunked = self.cfg.is_mla or bool(
+                req.override("chunked", plen > self.sc.chunk_threshold))
             if chunked:
                 if not self._admit_chunked(req.rid, req.tokens, req.max_new,
                                            retrieval=req.retrieval):
@@ -551,12 +521,12 @@ class Engine:
         continuous-batching loop serves them — the per-row streams are
         bit-identical to the legacy per-batch dense-cache loop (the
         pooled-vs-dense equality the paged tests pin). Engines the pool
-        cannot serve (ssm caches, ``paged=False``, prompts that don't
-        fit, a pool already mid-flight) fall back to that loop unchanged.
+        cannot serve (ssm caches, prompts that don't fit, a pool already
+        mid-flight) fall back to that loop unchanged.
         """
         prompts_np = np.asarray(prompts)
         B, S = prompts_np.shape
-        poolable = (self.sc.paged and self.cfg.family in POOL_FAMILIES
+        poolable = (self.cfg.family in POOL_FAMILIES
                     and S + max_new <= self.sc.max_len
                     and not self.busy()
                     and not self.slots.live_mask().any())
@@ -588,33 +558,26 @@ class Engine:
         return np.stack([np.asarray(t) for t in out], axis=1)
 
     # ------------------------------------------------------------------
-    # continuous batching (dense-cache families)
+    # continuous batching (the paged pool)
     # ------------------------------------------------------------------
 
     def _ensure_pool(self):
-        if self.sc.paged:
-            if self.pool is None:
-                assert self.cfg.family in POOL_FAMILIES, \
-                    "continuous batching requires dense KV caches"
-                self.pool = PagedKVPool(
-                    self.cfg, self.sc.n_slots, self.sc.max_len,
-                    page_size=self.sc.kv_page_size,
-                    total_pages=self.sc.pool_pages, tp=self.sc.tp)
-                if self._mesh_sharding is not None:
-                    # commit the pool buffers REPLICATED over the main mesh
-                    # from the start: every jit touching them (apply with
-                    # the shard_map seam, prefill splice, chunked extend)
-                    # then compiles for the mesh, and buffer donation stays
-                    # honorable (replicated in == replicated out)
-                    for k in ("k_pages", "v_pages"):
-                        self.pool.device[k] = jax.device_put(
-                            self.pool.device[k], self._mesh_sharding)
-                self._pending = np.zeros((self.sc.n_slots,), np.int32)
-        elif self.caches is None:
+        if self.pool is None:
             assert self.cfg.family in POOL_FAMILIES, \
                 "continuous batching requires dense KV caches"
-            self.caches = M.make_cache(self.cfg, self.sc.n_slots,
-                                       self.sc.max_len, tp=self.sc.tp)
+            self.pool = PagedKVPool(
+                self.cfg, self.sc.n_slots, self.sc.max_len,
+                page_size=self.sc.kv_page_size,
+                total_pages=self.sc.pool_pages, tp=self.sc.tp)
+            if self._mesh_sharding is not None:
+                # commit the pool buffers REPLICATED over the main mesh
+                # from the start: every jit touching them (apply with the
+                # shard_map seam, prefill splice, chunked extend) then
+                # compiles for the mesh, and buffer donation stays
+                # honorable (replicated in == replicated out)
+                for k in ("k_pages", "v_pages"):
+                    self.pool.device[k] = jax.device_put(
+                        self.pool.device[k], self._mesh_sharding)
             self._pending = np.zeros((self.sc.n_slots,), np.int32)
 
     # -- admission (batched, length-bucketed prefill) -------------------
@@ -662,8 +625,6 @@ class Engine:
         (None = service default: on when configured). Internal — callers
         admit through ``submit``."""
         self._ensure_pool()
-        if not self.sc.paged:
-            return [self._admit_one(rid, p, mn) for rid, p, mn in requests]
         admitted: Dict[int, List] = {}   # bucket_len -> [(slot, prompt)]
         ok: List[bool] = []
         for i, (rid, prompt, max_new) in enumerate(requests):
@@ -718,28 +679,6 @@ class Engine:
         for i, (slot, _) in enumerate(group):
             self._pending[slot] = nxt[i]
 
-    def _admit_one(self, request_id: int, prompt: np.ndarray, max_new: int,
-                   retrieval: Optional[bool] = None) -> bool:
-        """Prefill one request into a free slot (insertion into the pool)."""
-        if self.sc.paged:
-            return self._admit_many([(request_id, np.asarray(prompt),
-                                      max_new)], retrieval=[retrieval])[0]
-        assert self.cfg.family in POOL_FAMILIES, \
-            "continuous batching requires dense KV caches"
-        self._ensure_pool()
-        slot = self.slots.admit(request_id, len(prompt), max_new)
-        if slot is None:
-            return False
-        logits, c1 = self._prefill(self.params, jnp.asarray(prompt)[None])
-        S = len(prompt)
-        # splice the single-sequence cache into the pool at `slot`
-        self.caches["k"] = jax.lax.dynamic_update_slice(
-            self.caches["k"], c1["k"], (0, slot, 0, 0, 0))
-        self.caches["v"] = jax.lax.dynamic_update_slice(
-            self.caches["v"], c1["v"], (0, slot, 0, 0, 0))
-        self._pending[slot] = int(jnp.argmax(logits[0]))
-        return True
-
     # -- chunked prefill (long prompts, interleaved with decode) --------
 
     def _admit_chunked(self, request_id: int, prompt: np.ndarray,
@@ -748,7 +687,6 @@ class Engine:
         """Allocate slot + pages now; the prompt itself is prefilled in
         ``prefill_chunk``-sized spans by ``prefill_step`` so long prompts
         don't stall the decode pool."""
-        assert self.sc.paged, "chunked prefill needs the paged pool"
         self._ensure_pool()
         prompt = np.asarray(prompt)
         total = len(prompt) + max_new
@@ -898,7 +836,7 @@ class Engine:
         no chunked prefill pending and the retrieval subsystem quiescent
         (in-flight queries and waiting slots need per-step host turns)."""
         K = self.sc.fused_steps
-        if K <= 1 or not self.sc.paged or self._chunks:
+        if K <= 1 or self._chunks:
             return 1
         if self.retrieval is not None and self.retrieval.busy():
             return 1
@@ -910,12 +848,9 @@ class Engine:
         old list API returned). Stepped path: one decode step for every
         live slot. Fused path (``fused_steps`` K > 1): up to K steps run
         on device in one ``lax.scan`` and the host replays the emitted
-        event log. Paged path: per-slot lengths (each slot attends,
-        writes, and rotates at its own position); legacy path: shared
-        ``lengths.max()`` watermark."""
+        event log. Each slot attends, writes, and rotates at its own
+        position."""
         self._ensure_pool()
-        if not self.sc.paged:
-            return self._step_pool_dense()
         live = self._decode_live()
         if not live.any():
             if self.retrieval is not None:
@@ -1122,30 +1057,3 @@ class Engine:
                               embeds is not None]
         self.retrieval.note_splice(
             slot, tokens if tokens is not None else len(embeds))
-
-    def _step_pool_dense(self) -> StepEvents:
-        """Legacy baseline: dense pool, shared length watermark (max over
-        slots) — every slot pays the longest sequence's attention cost and
-        the sparse fallback cond sees the watermark, not true lengths."""
-        live = self.slots.live_mask()
-        if not live.any():
-            return StepEvents()
-        lengths = self.slots.lengths()
-        self.caches = dict(self.caches,
-                           length=jnp.asarray(lengths.max(), jnp.int32))
-        tok = jnp.asarray(self._pending)
-        logits, self.caches = self._decode(self.params, tok, self.caches,
-                                           self.sparse_params)
-        nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
-        self.stats["host_steps"] += 1
-        self.stats["decode_steps"] += 1
-        ev = StepEvents(steps=1)
-        for i in np.flatnonzero(live):
-            rid = self.slots.slots[i].request_id
-            ev.emissions.append((rid, int(i), int(self._pending[i])))
-            self._pending[i] = nxt[i]
-        self.slots.step(live)
-        for i in np.flatnonzero(live):
-            if self.slots.slots[i].done:
-                ev.finished.append(int(i))
-        return ev

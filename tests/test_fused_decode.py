@@ -233,8 +233,6 @@ def test_offload_config_validation():
         ServeConfig(offload="nope")
     with pytest.raises(ValueError):
         ServeConfig(fused_steps=0)
-    with pytest.raises(ValueError):
-        ServeConfig(fused_steps=4, paged=False)
 
 
 def test_offload_config_precedence_and_replace():

@@ -9,7 +9,10 @@ with a layer index inside each gather and scatter:
     as the engine donates it, neither slice a layer out of the pool nor
     restack or copy it: the layer loop carries the pool and updates it in
     place; with DSA the sparse branch gathers one view (K, for the index
-    projection) and the paged kernel reads the pool.
+    projection) and the paged kernel reads the pool;
+  * the layer loop of every pooled program (decode, DSA decode, presel
+    decode, chunked prefill) returns the residual as its first
+    [B, T, d] array, T = 1 or the chunk.
 """
 import re
 
@@ -200,6 +203,64 @@ def _extend_hlo(cfg, params, pool):
         pool["lengths"]).compile().as_text()
 
 
+def _presel_hlo(cfg, params, pool):
+    """The apply-phase decode program of the hetero offload split, with
+    pre-selected pages."""
+    B, NP = pool["page_table"].shape
+    ps = pool["k_pages"].shape[2]
+    mem = cfg.memory.replace(method="dsa")
+
+    def decode_paged_presel(p, tok, kp, vp, table, lengths, live, pidx):
+        return M.decode_step_paged_presel(
+            p, cfg, tok, {"k_pages": kp, "v_pages": vp, "page_table": table,
+                          "lengths": lengths}, live, pidx, mem,
+            page_size=ps, tp=4)
+
+    return jax.jit(decode_paged_presel, donate_argnums=(2, 3)).lower(
+        params, jax.ShapeDtypeStruct((B,), jnp.int32), pool["k_pages"],
+        pool["v_pages"], pool["page_table"], pool["lengths"],
+        jax.ShapeDtypeStruct((B,), jnp.bool_),
+        jax.ShapeDtypeStruct((cfg.n_layers, B, 2), jnp.int32)
+    ).compile().as_text()
+
+
+PROGRAMS = {"decode": _decode_hlo, "extend": _extend_hlo,
+            "dsa": _dsa_decode_hlo, "presel": _presel_hlo}
+
+
+def _while_rows(hlo, d, pool_shape):
+    """For each ``while`` of a program: T of the first [B, T, d] array of
+    its output tuple (0 if none), and whether that tuple carries the
+    stacked pool (the layer loop). A profile's reader classes a program as
+    decode when the largest T is 1 and as prefill when it is more."""
+    pool = r"\[" + ",".join(map(str, pool_shape)) + r"\]"
+    found = []
+    for line in hlo.splitlines():
+        if " while(" not in line or " = " not in line:
+            continue
+        out = line.split(" = ", 1)[1].split(" while(", 1)[0]
+        m = re.search(rf"\[\d+,(\d+),{d}\]", out)
+        found.append((int(m.group(1)) if m else 0,
+                      re.search(pool, out) is not None))
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "dsa", "presel", "extend"])
+def test_layer_loop_carries_the_residual_first(program):
+    """The layer loop of each pooled program returns the residual
+    [B, T, d] before any other [B, T, d] array: T = 1 for the decode
+    programs and the chunk for chunked prefill, so a profile's reader
+    classes each program by it."""
+    cfg, params, pool = _smoke()
+    hlo = PROGRAMS[program](cfg, params, pool)
+    found = _while_rows(hlo, cfg.d_model, pool["k_pages"].shape)
+    want = 8 if program == "extend" else 1       # _extend_hlo's chunk
+    # the layer loop: the one loop that carries the pool and a [B, T, d]
+    # array (an interpret-mode kernel's grid loop may carry the pool too)
+    assert [rows for rows, pooled in found if pooled and rows] == [want]
+    assert max(rows for rows, _ in found) == want
+
+
 @pytest.mark.parametrize("program", ["decode", "extend", "dsa"])
 def test_layer_loop_keeps_the_pool_in_place(program, monkeypatch):
     """With the DSA ``sparse_fn`` the sparse branch hands the paged kernel
@@ -218,8 +279,7 @@ def test_layer_loop_keeps_the_pool_in_place(program, monkeypatch):
 
     monkeypatch.setattr(ops, "paged_decode_attention", kernel)
     cfg, params, pool = _smoke()
-    hlo = {"decode": _decode_hlo, "extend": _extend_hlo,
-           "dsa": _dsa_decode_hlo}[program](cfg, params, pool)
+    hlo = PROGRAMS[program](cfg, params, pool)
     assert all(isinstance(kv, PooledKV) for pair in handed for kv in pair)
     assert len(handed) == (program == "dsa")
     assert "scatter(" in hlo

@@ -9,7 +9,9 @@ from hypothesis import given, settings, strategies as st
 from repro.configs import get_arch
 from repro.core.pipeline import MemoryPipeline, StageProfiler
 from repro.core import placement
-from repro.core.methods import dsa, seer, lserve, get_sparse_method
+from repro.core.methods import (dsa, seer, lserve, get_sparse_method,
+                                make_decode_attention)
+from repro.models import attention as A
 from repro.models import init_params, prefill, decode_step
 
 TP = 4
@@ -127,6 +129,35 @@ def test_placement_monotone_windows(log_ctx):
 def test_placement_prefers_sparse_at_long_context():
     cfg = get_arch("qwen3-32b")
     assert placement.choose_path(cfg, cfg.memory, 1 << 19) == "sparse"
+
+
+@pytest.mark.parametrize("length,branch", [(24, "dense"), (56, "sparse")],
+                         ids=["below_min_context", "inside_window"])
+def test_decode_attention_is_the_placement_fallback(length, branch):
+    """``make_decode_attention`` runs dense ``attention_decode`` below
+    ``min_context`` and the method's own sparse function inside
+    [min_context, fallback_context] (per-slot lengths, max decides)."""
+    cfg = get_arch("llama3.2-1b").smoke()
+    mem = cfg.memory.replace(method="dsa", top_k=16, min_context=32)
+    B, S, page = 2, 64, 8
+    kq, kk, kv, ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(kq, (B, 1, cfg.padded_heads(TP), cfg.hd),
+                          jnp.bfloat16)
+    kc = jax.random.normal(kk, (B, S, cfg.n_kv_heads, cfg.hd), jnp.bfloat16)
+    vc = jax.random.normal(kv, (B, S, cfg.n_kv_heads, cfg.hd), jnp.bfloat16)
+    lengths = jnp.asarray([length - 8, length], jnp.int32)
+    sp = dsa.dsa_init(ks, cfg, mem, stacked=False)
+    raw = dsa.make_sparse_fn(cfg, mem, tp=TP, page=page)
+    expect = {
+        "dense": lambda: A.attention_decode(q, kc, vc, lengths, cfg, tp=TP),
+        "sparse": lambda: raw(q, kc, vc, lengths, sp)}
+    fn = make_decode_attention(cfg, mem, "dsa", tp=TP, page=page)
+    got = np.asarray(jax.jit(lambda: fn(q, kc, vc, lengths, sp))(),
+                     np.float32)
+    want = {b: np.asarray(jax.jit(f)(), np.float32) for b, f in expect.items()}
+    np.testing.assert_array_equal(got, want[branch])
+    other = "sparse" if branch == "dense" else "dense"
+    assert not np.array_equal(got, want[other])
 
 
 def test_stage_costs_match_paper_table2_decades():

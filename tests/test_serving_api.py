@@ -5,7 +5,7 @@ and the ``generate()`` thin-wrapper guarantee.
 prompt batch fits the paged pool — it must stay BIT-IDENTICAL to the
 dense-cache loop it replaced (``_generate_batched``), leave no residue in
 the engine, and fall back to the dense loop whenever the pool cannot take
-the batch (ssm families, paged=False, oversized, pool busy)."""
+the batch (ssm families, oversized, pool busy)."""
 import numpy as np
 import jax
 import jax.numpy as jnp

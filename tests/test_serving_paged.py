@@ -181,18 +181,3 @@ def test_scheduler_paged_mixed_lengths(setup):
     assert all(len(r.tokens) == 4 for r in done.values())
     assert sch.throughput_tokens_per_s() > 0
     assert eng.pool.pages_in_use() == 0
-
-
-def test_legacy_watermark_pool_still_serves(setup):
-    """The paged=False baseline (dense pool, shared watermark) remains a
-    working scheduler target — it is the benchmark comparison point."""
-    cfg, params = setup
-    eng = Engine(cfg, params, ServeConfig(max_len=64, n_slots=3,
-                                          method="none", tp=4, paged=False))
-    sch = Scheduler(eng)
-    rng = np.random.default_rng(6)
-    rids = [sch.submit(rng.integers(0, cfg.vocab_size, size=10), max_new=4)
-            for _ in range(5)]
-    done = sch.run()
-    assert sorted(done) == sorted(rids)
-    assert all(len(r.tokens) == 4 for r in done.values())
